@@ -44,6 +44,7 @@ from benchmarks.common import emit
 from repro.core import projector
 from repro.core.quant import quantize_blockwise
 from repro.kernels import dispatch, ops, profile
+from repro.launch.cache import enable_compile_cache
 from repro.models import model_zoo
 from repro.serve import engine
 
@@ -93,7 +94,7 @@ def _effective_i8(op: str, M: int, K: int, n_pad: int, qblock: int,
     (dedup key: candidates that clamp identically time identically)."""
     return (dispatch.pick_tile(M, cand["bm"]),
             dispatch.fit_block(n_pad, cand["bn"], qblock),
-            dispatch.fit_block(K, cand["bk"]))
+            dispatch.fit_block(K, cand["bk"], 128))
 
 
 def sweep_int8(shapes, backend: str, *, iters: int, repeats: int,
@@ -223,6 +224,7 @@ def main(argv=None):
     ap.add_argument("--table", default=None,
                     help="merge measured entries into this table file")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     backend = args.backend or dispatch.default_backend("int8_matmul")
     if backend == "ref":

@@ -49,7 +49,8 @@ import json
 import re
 import time
 
-from repro.launch.mesh import force_host_device_count
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import force_host_device_count, make_mesh
 
 _BYTES = {"f32": 4, "f16": 2, "bf16": 2, "s32": 4, "u32": 4, "s8": 1,
           "u8": 1, "pred": 1, "s64": 8, "u64": 8, "f64": 8}
@@ -113,6 +114,7 @@ def main():
     import jax
     import jax.numpy as jnp
     import numpy as np
+    enable_compile_cache()
 
     from repro.config import QGaLoreConfig, ShapeCell, TrainConfig, replace
     from repro.core.optimizers import preset
@@ -121,7 +123,7 @@ def main():
     from repro.models import model_zoo
     from repro.train import step as step_lib
 
-    mesh = jax.make_mesh((args.devices, 1), ("data", "model"))
+    mesh = make_mesh((args.devices, 1), ("data", "model"))
     bundle = model_zoo.build_arch(args.arch, smoke=args.smoke,
                                   dtype=jnp.float32)
     rank = min(args.rank, 8 if args.smoke else args.rank)
@@ -214,7 +216,7 @@ def main():
     tp_runs: dict = {}
     for shape in ((args.devices, 1), (args.devices // tp, tp)):
         dname = f"{shape[0]}x{shape[1]}"
-        mesh_t = jax.make_mesh(shape, ("data", "model"))
+        mesh_t = make_mesh(shape, ("data", "model"))
         raw, specs_t = step_lib.build_train_step(
             bundle, qcfg_tp, tcfg, impl="fused", param_dtype=jnp.float32,
             mesh=mesh_t, dp_compress=True)

@@ -39,6 +39,7 @@ from benchmarks.common import emit, paired_ratio, paired_times
 from repro.core import projector, quant
 from repro.core.quant import quantize_blockwise
 from repro.kernels import dispatch, ops, profile
+from repro.launch.cache import enable_compile_cache
 
 
 def _time(fn, *args, iters=5):
@@ -57,6 +58,7 @@ def main(argv=None):
     ap.add_argument("--gate-out", default=None,
                     help="write the quantized>=dequant gate verdict JSON")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     key = jax.random.PRNGKey(0)
     x = jax.random.normal(key, (512, 1024), jnp.float32)

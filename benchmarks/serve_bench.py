@@ -43,6 +43,7 @@ import numpy as np
 from benchmarks.common import emit
 from repro.config import QGaLoreConfig
 from repro.kernels import dispatch
+from repro.launch.cache import enable_compile_cache
 from repro.models import model_zoo
 from repro.serve import engine
 from repro.serve.paged import PagedScheduler
@@ -316,6 +317,7 @@ def main(argv=None):
                     help="tiny shape-preserving configs (CI)")
     ap.add_argument("--out", default="BENCH_serve.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.paged:
         args.engines = "slot,paged"

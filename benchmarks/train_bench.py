@@ -43,6 +43,7 @@ from benchmarks.common import emit, paired_ratio
 from repro.config import QGaLoreConfig, ShapeCell, TrainConfig
 from repro.data.synthetic import batch_for_bundle
 from repro.kernels import dispatch, profile
+from repro.launch.cache import enable_compile_cache
 from repro.models import layers, model_zoo
 from repro.serve import engine
 from repro.train import step as step_lib
@@ -175,6 +176,7 @@ def main(argv=None):
                     help="tiny shape-preserving configs (CI)")
     ap.add_argument("--out", default="BENCH_train.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     report = {
         "meta": {

@@ -1,13 +1,15 @@
-"""Three-term roofline from dry-run artifacts (TPU v5e targets).
+"""Three-term roofline from dry-run artifacts.
 
-    compute    = HLO_FLOPs   / (chips × 197e12 FLOP/s)
-    memory     = HLO_bytes   / (chips × 819e9  B/s)
-    collective = coll_bytes  / (chips × 50e9   B/s per link)
+    compute    = HLO_FLOPs   / (chips × peak bf16 FLOP/s)
+    memory     = HLO_bytes   / (chips × peak HBM B/s)
+    collective = coll_bytes  / (chips × ICI B/s per link)
 
 HLO_FLOPs / HLO_bytes come from ``compiled.cost_analysis()`` of the
 PER-DEVICE partitioned module — i.e. already divided by the device count —
 so the per-chip terms divide by 1; we keep the formulas in per-chip form and
 document it. collective_bytes is parsed from the post-SPMD HLO (per device).
+The peaks come from :data:`PEAKS`, keyed by the artifact's
+``device_kind``.
 """
 from __future__ import annotations
 
@@ -16,9 +18,32 @@ import os
 from dataclasses import dataclass
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # B/s / chip
-ICI_BW = 50e9                # B/s / link
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    bf16_flops: float        # FLOP/s per chip
+    int8_ops: float          # OP/s per chip
+    hbm_bw: float            # B/s per chip
+    ici_bw: float            # B/s per link
+
+
+# Published per-chip peaks keyed by ``jax.Device.device_kind``.
+# "TPU v5 lite" is TPU v5e. Source: Google Cloud documentation, "TPU v5e"
+# (https://cloud.google.com/tpu/docs/v5e): 197 TFLOP/s bf16, 393 TOP/s
+# int8, 819 GB/s HBM, 1,600 Gbit/s of ICI over 4 links (50 GB/s each).
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(bf16_flops=197e12, int8_ops=393e12,
+                             hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    """The published peaks of ``device_kind``; an unknown kind raises."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
 @dataclass
@@ -30,6 +55,7 @@ class Roofline:
     bytes_accessed: float
     collective_bytes: float
     model_flops: float
+    peak_flops: float
     dram_s: float = 0.0          # analytic DRAM-stream estimate (see
     #                              analytic.cell_bytes — fusion-aware floor)
 
@@ -62,7 +88,7 @@ class Roofline:
         useful FLOPs / (chip peak × bound time)."""
         if self.bound_s == 0:
             return 0.0
-        return self.model_flops / (PEAK_FLOPS * self.bound_s)
+        return self.model_flops / (self.peak_flops * self.bound_s)
 
     def row(self) -> Dict[str, float]:
         return {
@@ -85,8 +111,9 @@ def from_artifact(art: Dict, corrected: bool = True) -> Optional[Roofline]:
     unrolled scans — XLA's CPU cost model counts while-loop bodies once
     (§Roofline-methodology). Bytes are scaled by the same factor (weight and
     activation traffic are also per-layer). Unrolled artifacts are exact and
-    used verbatim.
+    used verbatim. The artifact's ``device_kind`` picks the peaks.
     """
+    peaks = peaks_for(art.get("device_kind"))
     cost = art.get("cost_analysis") or {}
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))
@@ -118,14 +145,15 @@ def from_artifact(art: Dict, corrected: bool = True) -> Optional[Roofline]:
             pass
 
     return Roofline(
-        compute_s=flops / PEAK_FLOPS,
-        memory_s=byts / HBM_BW,
-        collective_s=coll / ICI_BW,
+        compute_s=flops / peaks.bf16_flops,
+        memory_s=byts / peaks.hbm_bw,
+        collective_s=coll / peaks.ici_bw,
         flops=flops,
         bytes_accessed=byts,
         collective_bytes=coll,
         model_flops=model_fl,
-        dram_s=dram / HBM_BW,
+        peak_flops=peaks.bf16_flops,
+        dram_s=dram / peaks.hbm_bw,
     )
 
 

@@ -1,71 +1,11 @@
-"""JAX API compatibility shims.
+"""Collective shims for the XLA:CPU SPMD partitioner.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` to
-``jax.shard_map`` with a changed signature::
-
-    old: shard_map(f, mesh, in_specs, out_specs, check_rep=True,
-                   auto=frozenset())
-    new: jax.shard_map(f, mesh=..., in_specs=..., out_specs=...,
-                       axis_names=<manual axes>, check_vma=True)
-
-The two express the manual/auto split inversely: the new API names the
-MANUAL axes (everything else stays automatic / GSPMD), the old API names
-the AUTO axes. ``check_vma`` is the new name for ``check_rep``.
-
-Every shard_map call in this repo goes through :func:`shard_map` below,
-which speaks the NEW keyword signature and lowers to whichever API the
-installed JAX provides — on old JAX (< jax.shard_map) it converts
-``axis_names`` to ``auto = mesh.axis_names - axis_names`` and
-``check_vma`` to ``check_rep``.
+``shard_map`` and ``lax.axis_size`` are used straight from the installed
+JAX (the 0.9 line); only the CPU partitioner gaps below need code here.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Optional, Set
-
 import jax
-
-
-def has_new_shard_map() -> bool:
-    """True when the installed JAX exposes top-level ``jax.shard_map``."""
-    try:
-        return callable(getattr(jax, "shard_map"))
-    except AttributeError:
-        # jax>=0.4.35 raises (DeprecationWarning machinery) instead of
-        # returning a missing-attribute sentinel.
-        return False
-
-
-def shard_map(f: Callable, *, mesh, in_specs: Any, out_specs: Any,
-              axis_names: Optional[Set[str]] = None,
-              check_vma: bool = True) -> Callable:
-    """New-API ``shard_map`` on any supported JAX.
-
-    ``axis_names``: the mesh axes the body is MANUAL over (receives
-    shard-local views + collectives); remaining axes stay automatic.
-    ``None`` means all mesh axes (both APIs' default).
-    """
-    if has_new_shard_map():
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kwargs)
-
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-    auto: frozenset = frozenset()
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _old_shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma,
-                          auto=auto)
-
-
-def axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` (new) / ``psum(1, axis)`` (old) — the static
-    size of a manual mesh axis, inside a shard_map body."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
 
 
 # ---------------------------------------------------------------------------

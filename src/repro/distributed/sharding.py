@@ -31,10 +31,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map  # noqa: F401 — canonical re-export: every
-# manual-collective entry point (train/step.py dp_compress, moe_ep tests,
-# future distributed serving) takes shard_map from here / repro.compat so the
-# old-vs-new jax.shard_map signature break stays fixed in ONE place.
 from repro.config import QGaLoreConfig
 from repro.core import quant
 from repro.core.adam8bit import Adam8bitState

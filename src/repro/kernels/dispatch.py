@@ -22,10 +22,9 @@ Selection order for :func:`default_backend`:
 2. ``REPRO_PALLAS_COMPILED=1`` (legacy knob) → ``pallas-tpu``.
 3. Platform detection: TPU → ``pallas-tpu``; anything else → ``ref``.
 
-If the requested backend has no registered implementation for an op,
-:func:`resolve` walks the fallback chain
-``pallas-tpu → pallas-interpret → ref`` so callers never crash on a
-partially-implemented op.
+:func:`resolve` answers with exactly the backend asked for and raises
+when the op has no implementation there: a ``pallas-tpu`` request is
+never quietly served by the interpreter or by ``ref``.
 
 Block-size autotune table
 -------------------------
@@ -56,14 +55,6 @@ KNOWN_BACKENDS = ("pallas-tpu", "pallas-interpret", "ref")
 
 # op name -> backend name -> callable
 _REGISTRY: Dict[str, Dict[str, Callable]] = {}
-
-# Fallback order when the preferred backend is not registered for an op.
-_FALLBACK = {
-    "pallas-tpu": ("pallas-tpu", "pallas-interpret", "ref"),
-    "pallas-interpret": ("pallas-interpret", "ref"),
-    "ref": ("ref", "pallas-interpret"),
-}
-
 
 def register(op: str, backend: str):
     """Decorator: register ``fn`` as the ``backend`` implementation of ``op``."""
@@ -104,21 +95,18 @@ def available_backends(op: str) -> Tuple[str, ...]:
 
 def resolve(op: str, backend: Optional[str] = None
             ) -> Tuple[str, Callable]:
-    """(backend_name, fn) for ``op``, honoring the fallback chain."""
+    """(backend_name, fn) for ``op`` on exactly the requested (or
+    default) backend; raises ``KeyError`` if the op has none there."""
     want = backend or default_backend(op)
     if want not in KNOWN_BACKENDS:
         raise ValueError(
             f"unknown backend {want!r} for op {op!r}; expected one of "
             f"{KNOWN_BACKENDS}")
-    impls = _REGISTRY.get(op)
-    if not impls:
-        raise KeyError(f"no implementations registered for op {op!r}")
-    for name in _FALLBACK[want]:
-        if name in impls:
-            return name, impls[name]
-    # last resort: anything registered
-    name = next(iter(impls))
-    return name, impls[name]
+    impls = _REGISTRY.get(op, {})
+    if want not in impls:
+        raise KeyError(f"op {op!r} has no {want!r} implementation "
+                       f"(registered: {sorted(impls)})")
+    return want, impls[want]
 
 
 def dispatch(op: str, *args, backend: Optional[str] = None, **kwargs):
@@ -242,17 +230,18 @@ def fit_block(dim: int, request: int, multiple_of: int = 1) -> int:
     matching the kernels' old ``min(tile, dim)`` clamp — rather than a
     grid of 1-wide tiles.
 
+    A dim with no divisor that is a multiple of ``multiple_of`` also
+    gets the whole dim: Mosaic takes a lane tile (``multiple_of=128``)
+    that is 128-aligned or spans the array, e.g. the unpadded d_ff 5461.
+
     The returned tile is never larger than ``dim`` (nor than its
     power-of-two bucket): a table entry tuned for a big bucket cannot
     force a small decode/smoke problem to pad up to the entry's tile.
     """
     request = max(1, min(request, dim, _bucket(dim)))
-    best = 1
-    for d in range(request, 0, -1):
-        if dim % d == 0 and d % multiple_of == 0:
-            best = d
-            break
-    if best * 4 <= request and dim % max(multiple_of, 1) == 0:
+    best = next((d for d in range(request, 0, -1)
+                 if dim % d == 0 and d % multiple_of == 0), dim)
+    if best * 4 <= request:
         return dim
     return best
 

@@ -46,6 +46,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _scale_tiles(scale, bn: int, block: int):
+    """(K, N // block) scales → (N // bn, bn // block, K): one lane-dense
+    (groups, K) slab per column tile. Mosaic requires a block's last two
+    dims to be (8, 128)-aligned or whole; a (bk, bn // block) window of
+    the stored layout is neither, while the slab's last two dims are
+    whole-or-``bk`` and each group's scale row broadcasts over the tile's
+    rows with no relayout."""
+    K, nb = scale.shape
+    return scale.T.reshape(nb // (bn // block), bn // block, K)
+
+
 def _kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, block: int, n_k: int):
     @pl.when(pl.program_id(2) == 0)
     def _init():
@@ -53,12 +64,12 @@ def _kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, block: int, n_k: int):
 
     x = x_ref[...].astype(jnp.float32)            # (BM, BK)
     q = q_ref[...]                                # (BK, BN) int8
-    s = s_ref[...]                                # (BK, BN // block)
+    s = s_ref[...]                                # (BN // block, BK)
     BK, BN = q.shape
     # Scale varies along the contraction axis K → fold it into the
     # activation per quant group instead of materializing deq(W) in VMEM.
     for g in range(BN // block):
-        xs = x * s[:, g][None, :]                 # (BM, BK)
+        xs = x * s[g:g + 1, :]                    # (BM, BK)
         acc_ref[:, g * block:(g + 1) * block] += jax.lax.dot_general(
             xs, q[:, g * block:(g + 1) * block].astype(jnp.float32),
             (((1,), (0,)), ((), ())),
@@ -88,13 +99,13 @@ def int8_matmul(x, q, scale, *, block: int = 256, bm: int = 128,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bk, bn // block), lambda i, j, k: (k, j)),
+            pl.BlockSpec((None, bn // block, bk), lambda i, j, k: (j, 0, k)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(x, q, scale)
+    )(x, q, _scale_tiles(scale, bn, block))
 
 
 def _kernel_t(g_ref, q_ref, s_ref, o_ref, acc_ref, *, block: int, n_n: int):
@@ -104,7 +115,7 @@ def _kernel_t(g_ref, q_ref, s_ref, o_ref, acc_ref, *, block: int, n_n: int):
 
     g = g_ref[...].astype(jnp.float32)            # (BM, BN)
     q = q_ref[...]                                # (BK, BN) int8
-    s = s_ref[...]                                # (BK, BN // block)
+    s = s_ref[...]                                # (BN // block, BK)
     BK, BN = q.shape
     # Contraction runs along N — the quant axis — so the scale applies
     # ONCE per group on the (BM, BK) accumulator: a true epilogue, raw
@@ -115,7 +126,7 @@ def _kernel_t(g_ref, q_ref, s_ref, o_ref, acc_ref, *, block: int, n_n: int):
             g[:, sl], q[:, sl].astype(jnp.float32),
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)   # (BM, BK)
-        acc_ref[...] += pdot * s[:, gg][None, :]
+        acc_ref[...] += pdot * s[gg:gg + 1, :]
 
     @pl.when(pl.program_id(2) == n_n - 1)
     def _done():
@@ -144,10 +155,10 @@ def int8_matmul_t(g, q, scale, *, block: int = 256, bm: int = 128,
         in_specs=[
             pl.BlockSpec((bm, bn), lambda i, k, n: (i, n)),
             pl.BlockSpec((bk, bn), lambda i, k, n: (k, n)),
-            pl.BlockSpec((bk, bn // block), lambda i, k, n: (k, n)),
+            pl.BlockSpec((None, bn // block, bk), lambda i, k, n: (n, 0, k)),
         ],
         out_specs=pl.BlockSpec((bm, bk), lambda i, k, n: (i, k)),
         out_shape=jax.ShapeDtypeStruct((M, K), g.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32)],
         interpret=interpret,
-    )(g, q, scale)
+    )(g, q, _scale_tiles(scale, bn, block))

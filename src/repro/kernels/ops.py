@@ -156,7 +156,7 @@ def int8_matmul(x, qt: QTensor, *, backend=None, interpret=None):
     blocks["bm"] = dispatch.pick_tile(xf.shape[0], blocks["bm"])
     xf, M = _pad_to(xf, 0, blocks["bm"])
     blocks["bn"] = dispatch.fit_block(N, blocks["bn"], qt.block)
-    blocks["bk"] = dispatch.fit_block(K, blocks["bk"])
+    blocks["bk"] = dispatch.fit_block(K, blocks["bk"], 128)
     out = fn(xf, qt.q, qt.scale, block=qt.block, **blocks)
     return out[:M, : qt.orig_last].reshape(*lead, qt.orig_last)
 
@@ -203,7 +203,7 @@ def _i8_call(name: str, x2, q, scale, block: int, orig: int):
     blocks["bm"] = dispatch.pick_tile(x2.shape[0], blocks["bm"])
     x2, M = _pad_to(x2, 0, blocks["bm"])
     blocks["bn"] = dispatch.fit_block(q.shape[1], blocks["bn"], block)
-    blocks["bk"] = dispatch.fit_block(x2.shape[1], blocks["bk"])
+    blocks["bk"] = dispatch.fit_block(x2.shape[1], blocks["bk"], 128)
     return fn(x2, q, scale, block=block, **blocks)[:M, :orig]
 
 
@@ -228,7 +228,7 @@ def _i8t_call(name: str, g2, q, scale, block: int):
     blocks["bm"] = dispatch.pick_tile(g2.shape[0], blocks["bm"])
     g2, M = _pad_to(g2, 0, blocks["bm"])
     blocks["bn"] = dispatch.fit_block(q.shape[1], blocks["bn"], block)
-    blocks["bk"] = dispatch.fit_block(q.shape[0], blocks["bk"])
+    blocks["bk"] = dispatch.fit_block(q.shape[0], blocks["bk"], 128)
     return fn(g2, q, scale, block=block, **blocks)[:M]
 
 
@@ -605,8 +605,11 @@ def fused_qgalore_update(
         q2p, _ = _pad_to(q2, 1, bn)
         wsp, _ = _pad_to(ws, 1, bn // wb)
         u01p, _ = _pad_to(u01, 1, bn, value=0.5)
+        # bm is the lane dim of the (rh, bm) P^T / scale^T / zero^T blocks
+        bm = dispatch.fit_block(mrows, blocks.get("bm", 256), 128)
         qn, sn, mn, vn = fn(lowp, mp, vp, proj.q, proj.scale, proj.zero,
-                            q2p, wsp, u01p, count, lr, bn=bn, **static)
+                            q2p, wsp, u01p, count, lr, bm=bm, bn=bn,
+                            **static)
         qn, sn = qn[:, :n_pad], sn[:, : n_pad // wb]
         mn, vn = mn[:, :n_pad], vn[:, :n_pad]
 

@@ -72,6 +72,8 @@ def run_cell(arch: str, cell_name: str, multi_pod: bool,
     art = {
         "arch": arch, "cell": cell_name,
         "mesh": "2x16x16" if multi_pod else "16x16",
+        # the chip this host-emulated mesh stands for (roofline peaks)
+        "device_kind": "TPU v5 lite",
         "kind": cell.kind, "refresh": refresh, "compress": compress,
         "params": model_zoo.count_params_analytic(cfg),
         "active_params": model_zoo.count_active_params(cfg),

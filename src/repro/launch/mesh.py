@@ -3,6 +3,7 @@ never touches jax device state (``force_host_device_count`` must therefore be
 called before anything else imports jax)."""
 from __future__ import annotations
 
+import math
 import os
 
 
@@ -17,18 +18,25 @@ def force_host_device_count(n: int) -> None:
             + f" --xla_force_host_platform_device_count={n}")
 
 
+def make_mesh(shape, axes):
+    """Every mesh of the repo is built here, with ``Auto`` axes, over the
+    first ``prod(shape)`` visible devices.
+
+    JAX 0.9's ``jax.make_mesh`` makes ``Explicit`` axes by default, under
+    which the sharding rules of ``repro.distributed`` (written for
+    GSPMD's automatic propagation) stop on ``ShardingTypeError``."""
+    import jax
+    from jax.sharding import AxisType
+    shape, axes = tuple(shape), tuple(axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=jax.devices()[:math.prod(shape)])
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single-pod (256 chips) or 2×16×16 multi-pod (512 chips)."""
-    import jax
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh for tests/small runs (e.g. (2, 2) on 4 host devices)."""
-    import jax
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes)
 
 
 def make_dp_mesh(num_devices: int = 0):
@@ -40,7 +48,7 @@ def make_dp_mesh(num_devices: int = 0):
     :func:`make_tp_mesh` to split devices between the two axes."""
     import jax
     d = num_devices or len(jax.devices())
-    return jax.make_mesh((d, 1), ("data", "model"))
+    return make_mesh((d, 1), ("data", "model"))
 
 
 def make_tp_mesh(tp: int, num_devices: int = 0):
@@ -54,4 +62,4 @@ def make_tp_mesh(tp: int, num_devices: int = 0):
         raise ValueError(
             f"tensor-parallel degree {tp} must be a positive divisor of "
             f"the device count {n} (got remainder {n % tp if tp else n})")
-    return jax.make_mesh((n // tp, tp), ("data", "model"))
+    return make_mesh((n // tp, tp), ("data", "model"))

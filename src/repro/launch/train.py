@@ -63,16 +63,20 @@ def main():
                     help="initialize jax.distributed (real clusters)")
     args = ap.parse_args()
 
-    from repro.launch.mesh import force_host_device_count
-    force_host_device_count(args.devices)
+    if args.devices:
+        from repro.launch.mesh import force_host_device_count
+        force_host_device_count(args.devices)
     import jax
     if args.multihost:
         jax.distributed.initialize()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     import logging
     import jax.numpy as jnp
     from repro.config import QGaLoreConfig, ShapeCell, TrainConfig
     from repro.core.optimizers import preset
+    from repro.launch.mesh import make_mesh, make_tp_mesh
     from repro.models import model_zoo
     from repro.train.trainer import Trainer
 
@@ -87,9 +91,8 @@ def main():
             raise SystemExit(
                 f"--mesh {args.mesh} needs {d * m} devices, have "
                 f"{len(jax.devices())} (use --devices on CPU)")
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
     elif args.tp:
-        from repro.launch.mesh import make_tp_mesh
         mesh = make_tp_mesh(args.tp)
 
     bundle = model_zoo.build_arch(args.arch, smoke=args.smoke,

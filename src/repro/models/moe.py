@@ -147,8 +147,7 @@ def moe_apply_ep(p: dict, x: jax.Array, cfg: ModelConfig, *,
     B, S, d = x.shape                       # LOCAL batch
     T = B * S
     E, k = mc.num_experts, mc.top_k
-    from repro.compat import axis_size
-    n_shards = axis_size(ep_axis)
+    n_shards = jax.lax.axis_size(ep_axis)
     assert E % n_shards == 0, (E, n_shards)
     E_loc = E // n_shards
     xt = x.reshape(T, d)
@@ -206,21 +205,17 @@ def moe_ep_sharded(p: dict, x: jax.Array, cfg: ModelConfig, *, mesh,
                    ep_axis: str, capacity_factor: float = 1.25,
                    dtype=jnp.bfloat16) -> Tuple[jax.Array, jax.Array]:
     """Top-level expert-parallel entry: wraps :func:`moe_apply_ep` in a
-    ``shard_map`` (via the ``repro.compat`` shim, so it runs on both the
-    old ``jax.experimental.shard_map`` and the new ``jax.shard_map`` API)
-    manual over ``ep_axis``.
+    ``jax.shard_map`` manual over ``ep_axis``.
 
     Expert stacks shard on their leading E dim over ``ep_axis``; router and
     shared-expert weights enter replicated; the token batch shards on dim 0.
     The aux loss is pmeaned over the shards (per-shard top-1 densities).
 
-    The region is manual over ALL mesh axes (``axis_names=None``): the body
-    only issues ``ep_axis`` collectives, and the older XLA behind the compat
-    shim miscompiles partial-manual subgroups for this program — non-EP
-    inputs therefore enter replicated (gathered) over the other axes."""
+    The region is manual over ALL mesh axes: the body only issues
+    ``ep_axis`` collectives, and XLA:CPU miscompiles partial-manual
+    subgroups for this program — non-EP inputs therefore enter
+    replicated (gathered) over the other axes."""
     from jax.sharding import PartitionSpec as P
-
-    from repro.compat import shard_map
 
     def inner(pp, xb):
         out, aux = moe_apply_ep(pp, xb, cfg, ep_axis=ep_axis,
@@ -229,9 +224,8 @@ def moe_ep_sharded(p: dict, x: jax.Array, cfg: ModelConfig, *, mesh,
 
     pspecs = {k: (P(ep_axis) if k.startswith("experts_") else P())
               for k in p}
-    f = shard_map(inner, mesh=mesh, axis_names=None,
-                  in_specs=(pspecs, P(ep_axis)),
-                  out_specs=(P(ep_axis), P()), check_vma=False)
+    f = jax.shard_map(inner, mesh=mesh, in_specs=(pspecs, P(ep_axis)),
+                      out_specs=(P(ep_axis), P()), check_vma=False)
     return f(p, x)
 
 

@@ -161,6 +161,17 @@ def build_train_step(bundle: ModelBundle, qcfg,
     steady-state compressed step already does), so plain-mode and
     dist-refresh trajectories agree only to clip-scale tolerance.
     """
+    from repro.kernels import dispatch as kdispatch
+    from repro.models import layers as _layers
+    if mesh is not None and mesh.size > 1:
+        # A Mosaic kernel compiles only inside a fully manual shard_map;
+        # GSPMD refuses it ("cannot be automatically partitioned").
+        if kdispatch.default_backend() == "pallas-tpu":
+            raise ValueError(
+                f"the pallas-tpu kernels do not run under a {mesh.size}-"
+                "device mesh yet: the sharded step does not wrap them in a "
+                "manual shard_map (ROADMAP Speed 7). Set "
+                "REPRO_KERNEL_BACKEND=ref for multi-device runs.")
     rules = as_rules(qcfg)
     base = rules.base
     if specs is None:
@@ -176,8 +187,6 @@ def build_train_step(bundle: ModelBundle, qcfg,
     seg_keys = {bundle.seg_key(i) for i in range(len(bundle.segments))}
     zero2_dims = dict(zero2_dims or {})
 
-    from repro.kernels import dispatch as kdispatch
-    from repro.models import layers as _layers
     logging.getLogger(__name__).info(
         "train step: kernel backend=%s quantized_dense=%s (backend=%s) "
         "fused_update=%s batch_leaves=%s groups=%s",
@@ -412,8 +421,7 @@ def build_train_step(bundle: ModelBundle, qcfg,
                 gspecs.append(P())
         grads_specs = jax.tree_util.tree_unflatten(gtreedef, gspecs)
 
-        from repro.compat import shard_map
-        loss, metrics, grads = shard_map(
+        loss, metrics, grads = jax.shard_map(
             inner, mesh=mesh, axis_names=set(dp_axes),
             in_specs=(_manual_specs(params), _manual_specs(proj_trees),
                       batch_specs),
@@ -493,8 +501,8 @@ def build_train_step(bundle: ModelBundle, qcfg,
             if qgalore._eff_cfg(specs[i], rules).adaptive_rank}
         shard_ids = jnp.arange(dp_size, dtype=jnp.int32)
         shard_ids_all = jnp.arange(refresh_world, dtype=jnp.int32)
-        new_low, new_proj, sims, ratios = shard_map(
-            refresh_inner, mesh=mesh, axis_names=None,
+        new_low, new_proj, sims, ratios = jax.shard_map(
+            refresh_inner, mesh=mesh,
             in_specs=(front0(gd), front0(refresh_proj),
                       front0(refresh_masks), P(), P(dp_axes),
                       P(refresh_axes)),

@@ -352,10 +352,12 @@ class Trainer:
                     self.maybe_restore()
                     step = self.start_step
                 continue
-            dt = time.monotonic() - t0
-            self.stragglers.observe(step, dt)
+            # reading the scalars waits for the step program to finish on
+            # the device, so dt is the step's time and not its dispatch
             row = {k: float(v) for k, v in metrics.items()
                    if np.ndim(v) == 0}
+            dt = time.monotonic() - t0
+            self.stragglers.observe(step, dt)
             row["step"] = step
             row["dt"] = dt
             self.history.append(row)

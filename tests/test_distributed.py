@@ -19,6 +19,7 @@ def run_py(body: str, devices: int = 8, timeout: int = 600):
         import os
         os.environ["XLA_FLAGS"] = \
             "--xla_force_host_platform_device_count={devices}"
+        from repro.launch.mesh import make_mesh
         {textwrap.indent(textwrap.dedent(body), '        ').strip()}
     """)
     env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
@@ -39,7 +40,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
         from repro.train import step as step_lib
         from repro.data.synthetic import batch_for_bundle
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         bundle = model_zoo.build_arch("llama-60m", smoke=True,
                                       dtype=jnp.float32)
         qcfg = preset("qgalore", QGaLoreConfig(rank=8, min_dim=32))
@@ -81,6 +82,32 @@ def test_sharded_train_step_runs_and_matches_single_device():
     assert "OK" in out
 
 
+def test_pallas_tpu_under_multi_device_mesh_names_the_cause():
+    """The Mosaic kernels are not wrapped in a manual shard_map yet, so a
+    multi-device step on pallas-tpu must stop at build time with the
+    cause, not inside the compiler. One device is still allowed."""
+    out = run_py("""
+        import os
+        os.environ["REPRO_KERNEL_BACKEND"] = "pallas-tpu"
+        import jax.numpy as jnp, pytest
+        from repro.config import QGaLoreConfig, TrainConfig
+        from repro.models import model_zoo
+        from repro.train import step as step_lib
+
+        bundle = model_zoo.build_arch("llama-60m", smoke=True,
+                                      dtype=jnp.float32)
+        qcfg = QGaLoreConfig(rank=8, min_dim=32)
+        tcfg = TrainConfig(global_batch=4, seq_len=32)
+        step_lib.build_train_step(bundle, qcfg, tcfg,
+                                  mesh=make_mesh((1, 1), ("data", "model")))
+        with pytest.raises(ValueError, match="Speed 7"):
+            step_lib.build_train_step(
+                bundle, qcfg, tcfg, mesh=make_mesh((2, 1), ("data", "model")))
+        print("OK")
+    """, devices=2)
+    assert "OK" in out
+
+
 def test_elastic_checkpoint_reshard():
     """Save on a (4,2) mesh, restore onto (2,2) with different shardings —
     the elastic-scaling path."""
@@ -99,7 +126,7 @@ def test_elastic_checkpoint_reshard():
         state = step_lib.init_state(bundle, qcfg, jax.random.PRNGKey(0),
                                     jnp.float32)
 
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_a = make_mesh((4, 2), ("data", "model"))
         p_sh_a = sh.param_sharding(state.params, mesh_a)
         o_sh_a = sh.opt_state_sharding(state.params, state.opt, qcfg,
                                        mesh_a)
@@ -110,8 +137,7 @@ def test_elastic_checkpoint_reshard():
         mgr.save(7, st_a, {"note": "elastic"})
 
         # restore on a DIFFERENT mesh shape
-        mesh_b = jax.make_mesh((2, 2), ("data", "model"),
-                               devices=jax.devices()[:4])
+        mesh_b = make_mesh((2, 2), ("data", "model"))
         abs_state = step_lib.abstract_state(bundle, qcfg, jnp.float32)
         p_sh_b = sh.param_sharding(abs_state.params, mesh_b)
         o_sh_b = sh.opt_state_sharding(abs_state.params, abs_state.opt,
@@ -139,7 +165,7 @@ def test_sharded_decode_runs():
         from repro.serve import engine, shard as sshard
         from repro.train import step as step_lib
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         bundle = model_zoo.build_arch("yi-9b", smoke=True,
                                       dtype=jnp.float32)
         params = step_lib.prepare_params(
@@ -191,8 +217,7 @@ def test_sharded_cache_pool_continuous_decode():
                         max_new_tokens=int(rng.integers(2, 6)))
                 for r in range(6)]
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"),
-                             devices=jax.devices()[:4])
+        mesh = make_mesh((2, 2), ("data", "model"))
         sh = sshard.pool_sharding(bundle, num_slots=4, max_len=32,
                                   mesh=mesh, dtype=jnp.float32)
         with mesh:
@@ -244,8 +269,7 @@ def test_sharded_paged_pool_continuous_decode():
         # num_blocks divisible by the data axis (2) for block sharding
         kw = dict(num_slots=4, max_len=32, block_size=8, num_blocks=18,
                   prefill_chunk=8, dtype=jnp.float32)
-        mesh = jax.make_mesh((2, 2), ("data", "model"),
-                             devices=jax.devices()[:4])
+        mesh = make_mesh((2, 2), ("data", "model"))
         sh = sshard.paged_pool_sharding(bundle, kw["num_blocks"],
                                         kw["block_size"], mesh,
                                         dtype=jnp.float32)
@@ -311,8 +335,7 @@ def test_dp_compress_parity_1dev_vs_8dev():
         cell = ShapeCell("t", 32, 8, "train")
 
         def run(d):
-            mesh = jax.make_mesh((d, 1), ("data", "model"),
-                                 devices=jax.devices()[:d])
+            mesh = make_mesh((d, 1), ("data", "model"))
             raw, specs = step_lib.build_train_step(
                 bundle, qcfg, tcfg, impl="fused", param_dtype=jnp.float32,
                 mesh=mesh, dp_compress=True)
@@ -359,7 +382,7 @@ def test_dist_refresh_matches_replicated():
         from repro.train import step as step_lib
         from repro.data.synthetic import batch_for_bundle
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         bundle = model_zoo.build_arch("llama-60m", smoke=True,
                                       dtype=jnp.float32)
         tcfg = TrainConfig(global_batch=8, seq_len=32, grad_clip=0.0)
@@ -426,7 +449,7 @@ def test_zero_sharded_state_matches_and_reshards():
                                                compress_dp_grads=True))
         tcfg = TrainConfig(global_batch=8, seq_len=32, grad_clip=1.0)
         cell = ShapeCell("t", 32, 8, "train")
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         raw, specs = step_lib.build_train_step(
             bundle, qcfg, tcfg, impl="fused", param_dtype=jnp.float32,
             mesh=mesh, dp_compress=True)
@@ -479,8 +502,7 @@ def test_zero_sharded_state_matches_and_reshards():
         d = tempfile.mkdtemp()
         mgr = CheckpointManager(d, async_save=False)
         mgr.save(3, states["zero"], {"note": "zero"})
-        mesh_b = jax.make_mesh((2, 2), ("data", "model"),
-                               devices=jax.devices()[:4])
+        mesh_b = make_mesh((2, 2), ("data", "model"))
         abs_state = step_lib.abstract_state(bundle, qcfg, jnp.float32)
         ss_b = step_lib.TrainState(
             sh.param_sharding(abs_state.params, mesh_b),
@@ -514,7 +536,7 @@ def test_zero2_reduce_scatter_matches_pmean():
         from repro.train import step as step_lib
         from repro.data.synthetic import batch_for_bundle
 
-        mesh = jax.make_mesh((8, 1), ("data", "model"))
+        mesh = make_mesh((8, 1), ("data", "model"))
         bundle = model_zoo.build_arch("llama-60m", smoke=True,
                                       dtype=jnp.float32)
         qcfg = preset("qgalore", QGaLoreConfig(rank=8, min_dim=32,
@@ -587,7 +609,7 @@ def test_dp_compress_matches_plain():
         from repro.train import step as step_lib
         from repro.data.synthetic import batch_for_bundle
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         bundle = model_zoo.build_arch("llama-60m", smoke=True,
                                       dtype=jnp.float32)
         qcfg = preset("qgalore", QGaLoreConfig(rank=8, min_dim=32))
@@ -651,8 +673,7 @@ def test_adarank_zero_compressed_forced_transition():
                                grad_clip=1.0, log_every=0,
                                checkpoint_dir=ckpt_dir,
                                async_checkpoint=False)
-            mesh = mesh or jax.make_mesh((d, 1), ("data", "model"),
-                                         devices=jax.devices()[:d])
+            mesh = mesh or make_mesh((d, 1), ("data", "model"))
             return Trainer(bundle, tcfg, qcfg, cell=cell, impl="fused",
                            param_dtype=jnp.float32, mesh=mesh,
                            zero_shard=True)
@@ -676,8 +697,7 @@ def test_adarank_zero_compressed_forced_transition():
                                    rtol=1e-3, atol=1e-3)
 
         # (c) elastic post-shrink restore onto a (2,2) mesh
-        mesh_b = jax.make_mesh((2, 2), ("data", "model"),
-                               devices=jax.devices()[:4])
+        mesh_b = make_mesh((2, 2), ("data", "model"))
         trb = make(2, ckpt_dir=d8, mesh=mesh_b)
         assert trb.mgr.read_meta()["rank_overrides"]
         assert trb.maybe_restore() == 6

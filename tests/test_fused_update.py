@@ -158,9 +158,14 @@ class TestDispatch:
         assert dispatch.default_backend("fused_qgalore_update") == want
 
     def test_fallback_chain(self):
+        """There is no fallback chain: a backend the op lacks raises
+        instead of quietly running the interpreter or the ref oracle."""
         dispatch.register("_test_only_op", "ref")(lambda: "ref")
-        name, fn = dispatch.resolve("_test_only_op", "pallas-tpu")
+        name, fn = dispatch.resolve("_test_only_op", "ref")
         assert name == "ref" and fn() == "ref"
+        for want in ("pallas-tpu", "pallas-interpret"):
+            with pytest.raises(KeyError, match=want):
+                dispatch.resolve("_test_only_op", want)
 
     def test_tuned_blocks_bucketing(self):
         b = dispatch.tuned_blocks("fused_qgalore_update", (1000, 900),
@@ -190,6 +195,8 @@ class TestTileFitting:
         assert dispatch.fit_block(768, 512, 256) == 256
         assert dispatch.fit_block(192, 128) == 96
         assert dispatch.fit_block(512, 512) == 512
+        assert dispatch.fit_block(5461, 512, 128) == 5461
+        assert dispatch.fit_block(3200, 256, 128) == 128
         assert dispatch.fit_block(256, 1024) == 256
         # awkward dims fall back to one tile, not a grid of 1-wide tiles
         assert dispatch.fit_block(197, 128) == 197

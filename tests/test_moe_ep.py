@@ -24,11 +24,9 @@ def test_moe_ep_matches_plain():
 
         ref, aux_ref = moe_lib.moe_apply(params, x, cfg, dtype=jnp.float32)
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
 
-        # expert weights sharded on E over data; router/shared replicated —
-        # moe_ep_sharded builds the shard_map through the repro.compat shim
-        # (old jax.experimental.shard_map vs new jax.shard_map).
+        # expert weights sharded on E over data; router/shared replicated
         got, aux_got = jax.jit(functools.partial(
             moe_lib.moe_ep_sharded, cfg=cfg, mesh=mesh, ep_axis="data",
             dtype=jnp.float32))(params, x)

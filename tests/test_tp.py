@@ -34,6 +34,7 @@ def run_py(body: str, devices: int = 8, timeout: int = 600):
         import os
         os.environ["XLA_FLAGS"] = \
             "--xla_force_host_platform_device_count={devices}"
+        from repro.launch.mesh import make_mesh
         {textwrap.indent(textwrap.dedent(body), '        ').strip()}
     """)
     env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
@@ -83,7 +84,7 @@ def test_tp_adarank_parity_2x4_vs_1dev_and_elastic_restore():
                            zero_shard=True)
 
         d = tempfile.mkdtemp()
-        mesh_tp = jax.make_mesh((2, 4), ("data", "model"))
+        mesh_tp = make_mesh((2, 4), ("data", "model"))
         tr_tp = make(mesh_tp, ckpt_dir=d)
         # the TP annotation really landed on the specs
         ann = {s.path: (s.shard_dim, s.tp) for s in tr_tp.specs
@@ -97,8 +98,7 @@ def test_tp_adarank_parity_2x4_vs_1dev_and_elastic_restore():
             if s.galore:
                 assert s.rank == 4, s      # live migration really shrank
 
-        mesh_1 = jax.make_mesh((1, 1), ("data", "model"),
-                               devices=jax.devices()[:1])
+        mesh_1 = make_mesh((1, 1), ("data", "model"))
         tr1 = make(mesh_1)
         hist1 = tr1.run()
         assert tr1.controller.rank_transition_summary() == trans_tp
@@ -107,7 +107,7 @@ def test_tp_adarank_parity_2x4_vs_1dev_and_elastic_restore():
                                    rtol=1e-3, atol=1e-3)
 
         # elastic: the (2,4) ZeRO+TP checkpoint restores onto (8,1)
-        mesh_dp = jax.make_mesh((8, 1), ("data", "model"))
+        mesh_dp = make_mesh((8, 1), ("data", "model"))
         tr_dp = make(mesh_dp, ckpt_dir=d)
         assert tr_dp.mgr.read_meta()["rank_overrides"]
         assert tr_dp.maybe_restore() == 6
@@ -151,7 +151,7 @@ def test_tp_refresh_no_full_rank_materialization():
                                                compress_dp_grads=True))
         tcfg = TrainConfig(global_batch=8, seq_len=32, grad_clip=0.0)
         cell = ShapeCell("t", 32, 8, "train")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         raw, specs = step_lib.build_train_step(
             bundle, qcfg, tcfg, impl="fused", param_dtype=jnp.float32,
             mesh=mesh, dp_compress=True)
@@ -245,8 +245,8 @@ def test_tp_per_device_state_bytes():
                 is_leaf=lambda x: quant.is_qtensor(x) or x is None)[0]
             return inner, proj
 
-        mesh_tp = jax.make_mesh((2, 4), ("data", "model"))
-        mesh_dp = jax.make_mesh((8, 1), ("data", "model"))
+        mesh_tp = make_mesh((2, 4), ("data", "model"))
+        mesh_dp = make_mesh((8, 1), ("data", "model"))
         specs_tp = sh.annotate_tp(specs, mesh_tp)
         inner_tp, proj_tp = place(mesh_tp)
         inner_dp, proj_dp = place(mesh_dp)
@@ -294,10 +294,9 @@ def test_tp_sharded_subspace_collectives():
     out = run_py("""
         import functools, jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro import compat
         from repro.core import projector
 
-        mesh = jax.make_mesh((4,), ("x",))
+        mesh = make_mesh((4,), ("x",))
         psum = functools.partial(jax.lax.psum, axis_name="x")
         G = jax.random.normal(jax.random.PRNGKey(0), (48, 64), jnp.float32)
         rank = 8
@@ -315,7 +314,7 @@ def test_tp_sharded_subspace_collectives():
                 f = functools.partial(projector.sharded_subspace,
                                       rank=rank, side=side,
                                       shard_dim=shard_dim, psum=psum)
-                P_sh = compat.shard_map(
+                P_sh = jax.shard_map(
                     f, mesh=mesh, in_specs=(g_spec,), out_specs=p_spec,
                     check_vma=False)(G)
                 sim = float(projector.subspace_similarity(P_ref, P_sh))
@@ -324,7 +323,7 @@ def test_tp_sharded_subspace_collectives():
                 g = functools.partial(projector.explained_ratio_sharded,
                                       side=side, shard_dim=shard_dim,
                                       psum=psum)
-                ratio_sh = compat.shard_map(
+                ratio_sh = jax.shard_map(
                     g, mesh=mesh, in_specs=(g_spec, p_spec),
                     out_specs=P(None), check_vma=False)(G, Pf)
                 np.testing.assert_allclose(
