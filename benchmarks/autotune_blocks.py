@@ -249,7 +249,7 @@ def main(argv=None):
         rank = args.rank
 
     rows: List[dict] = []
-    with profile.timed("autotune/sweep"):
+    with profile.span("autotune/sweep"):
         sweep_int8(shapes, backend, iters=iters, repeats=repeats,
                    qblock=qblock, rows=rows)
         sweep_fused(weights, backend, iters=iters, repeats=repeats,

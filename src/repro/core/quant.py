@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import profile
+
 DEFAULT_BLOCK = 256
 _EPS = 1e-12
 
@@ -293,7 +295,9 @@ def quantize_blockwise(
         t = xb / scale + zero
 
     if stochastic_key is not None:
-        u = jax.random.uniform(stochastic_key, t.shape, dtype=jnp.float32)
+        with profile.scope("repro.sr_randoms"):
+            u = jax.random.uniform(stochastic_key, t.shape,
+                                   dtype=jnp.float32)
         codes = jnp.floor(t + u)
     else:
         codes = jnp.round(t)
@@ -354,7 +358,8 @@ def requantize_sr(
 
 def stochastic_round(x: jax.Array, key: jax.Array) -> jax.Array:
     """SR to integers: floor(x + u)."""
-    u = jax.random.uniform(key, x.shape, dtype=jnp.float32)
+    with profile.scope("repro.sr_randoms"):
+        u = jax.random.uniform(key, x.shape, dtype=jnp.float32)
     return jnp.floor(x.astype(jnp.float32) + u)
 
 

@@ -244,8 +244,9 @@ def _qdense_core_fwd(name, block, orig, x2, shadow, q, scale):
 def _qdense_core_bwd(name, block, orig, res, g):
     x2, shadow, q, scale = res
     dx = _i8t_call(name, g, q, scale, block).astype(x2.dtype)
-    dw = jnp.einsum("mk,mn->kn", x2.astype(jnp.float32),
-                    g.astype(jnp.float32)).astype(shadow.dtype)
+    with profile.scope("repro.wgrad"):
+        dw = jnp.einsum("mk,mn->kn", x2.astype(jnp.float32),
+                        g.astype(jnp.float32)).astype(shadow.dtype)
     return dx, dw, quant._zero_cotangent(q), quant._zero_cotangent(scale)
 
 
@@ -264,8 +265,9 @@ def _qdense_t_core_fwd(name, block, orig, x2, shadow, q, scale):
 def _qdense_t_core_bwd(name, block, orig, res, g):
     x2, shadow, q, scale = res
     dx = _i8_call(name, g, q, scale, block, orig).astype(x2.dtype)
-    dw = jnp.einsum("mv,md->vd", g.astype(jnp.float32),
-                    x2.astype(jnp.float32)).astype(shadow.dtype)
+    with profile.scope("repro.wgrad"):
+        dw = jnp.einsum("mv,md->vd", g.astype(jnp.float32),
+                        x2.astype(jnp.float32)).astype(shadow.dtype)
     return dx, dw, quant._zero_cotangent(q), quant._zero_cotangent(scale)
 
 
@@ -442,7 +444,8 @@ def sr_requant_update(qt: QTensor, update, key, *, backend=None,
     pad = q2.shape[-1] - upd.shape[-1]
     if pad:
         upd = jnp.pad(upd, ((0, 0), (0, pad)))
-    u01 = jax.random.uniform(key, q2.shape, jnp.float32)
+    with profile.scope("repro.sr_randoms"):
+        u01 = jax.random.uniform(key, q2.shape, jnp.float32)
     blocks = dispatch.tuned_blocks("sr_requant", q2.shape, backend=name)
     blocks["br"] = dispatch.fit_block(q2.shape[0], blocks["br"])
     blocks["bc"] = dispatch.fit_block(q2.shape[1], blocks["bc"], qt.block)
@@ -551,7 +554,8 @@ def fused_qgalore_update(
 
     # SR randoms over the REAL (row, padded-col) region — matches the
     # draw quant.requantize_sr makes, so fused == unfused bit-for-bit.
-    u01 = jax.random.uniform(key, q2.shape, jnp.float32)
+    with profile.scope("repro.sr_randoms"):
+        u01 = jax.random.uniform(key, q2.shape, jnp.float32)
 
     blocks = dispatch.tuned_blocks("fused_qgalore_update",
                                    (mrows, n_pad), backend=name)

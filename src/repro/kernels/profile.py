@@ -1,4 +1,5 @@
-"""Lightweight per-op profiling counters behind ``REPRO_PROFILE=1``.
+"""Profiling hooks: trace-time op counters, named device scopes and host
+spans.
 
 The quantized hot paths run inside ``jit``, so per-call wall time cannot
 be observed from Python without defeating the fusion being measured.
@@ -8,25 +9,44 @@ What CAN be recorded cheaply and without perturbing the compiled graph:
   while tracing, logging how many times each kernel op is baked into a
   compiled program and the HBM bytes / FLOPs one execution of that call
   moves. Re-traces count again (that is itself a useful signal: an
-  unexpected recount means shape churn → recompiles).
-* **eager wall timers** — :func:`timed` wraps host-side regions (the
-  benches' timing loops, the autotune sweep) with a named wall-clock
-  accumulator.
+  unexpected recount means shape churn → recompiles). Only under
+  ``REPRO_PROFILE=1``.
+* **device scopes** — :func:`scope` is ``jax.named_scope`` for one of the
+  names in :data:`SCOPES`. It sets the ``op_name`` metadata of every HLO
+  instruction traced inside it, so a profiler trace can sum device time
+  per scope; it changes nothing that runs. Scopes wrap XLA work: the
+  Pallas kernels are found by their own instruction names.
+* **host spans** — :func:`span` wraps a host-side region in a
+  ``jax.profiler.TraceAnnotation``, which is written only while a
+  profiler session runs, so device idle gaps can be set against what the
+  host was doing. Under ``REPRO_PROFILE=1`` it also keeps a wall-clock
+  accumulator (``wall_us``, ``wall_calls``) beside the counters.
 
-Everything is a no-op unless ``REPRO_PROFILE=1`` at call time, so the
-hooks cost one ``os.environ`` dict lookup on the trace path and nothing
-at execution time. The benches dump :func:`snapshot` into their JSON
-artifacts so the next perf gap is diagnosable from CI output instead of
-rerunning A/B sweeps by hand.
+The benches attach :func:`snapshot` to their JSON artifacts
+(:func:`maybe_attach`) so the next perf gap is diagnosable from CI output
+instead of rerunning A/B sweeps by hand.
 """
 from __future__ import annotations
 
 import contextlib
-import json
 import os
 import time
 from collections import defaultdict
 from typing import Any, Dict, Optional
+
+import jax
+
+# Device scopes of the training step, innermost wins where they nest:
+#   repro.attention   scores, mask, softmax and PV (models/attention.py)
+#   repro.wgrad       weight gradients x^T g and their rank-r projection
+#   repro.layer       the rest of a decoder block: norms, RoPE, SwiGLU gate,
+#                     residuals
+#   repro.head_loss   final norm, logits and cross-entropy
+#   repro.optimizer   gradient clipping and the optimizer update around the
+#                     fused kernel: dense 8-bit Adam, moment (de)quantization
+#   repro.sr_randoms  the uniform draws that feed stochastic rounding
+SCOPES = ("repro.attention", "repro.wgrad", "repro.layer",
+          "repro.head_loss", "repro.optimizer", "repro.sr_randoms")
 
 
 def enabled() -> bool:
@@ -56,19 +76,28 @@ def record(op: str, *, nbytes: int = 0, flops: int = 0,
         row["meta"] = dict(meta)
 
 
+def scope(name: str):
+    """``jax.named_scope(name)`` for a name declared in :data:`SCOPES`."""
+    if name not in SCOPES:
+        raise ValueError(f"undeclared device scope {name!r}; have {SCOPES}")
+    return jax.named_scope(name)
+
+
 @contextlib.contextmanager
-def timed(name: str):
-    """Eager wall-clock accumulator for host-side regions."""
-    if not enabled():
-        yield
-        return
-    t0 = time.monotonic()
-    try:
-        yield
-    finally:
-        row = _COUNTS[name]
-        row["wall_us"] += (time.monotonic() - t0) * 1e6
-        row["wall_calls"] += 1
+def span(name: str):
+    """A named host-side region: a profiler ``TraceAnnotation`` always,
+    and the wall-clock accumulator under ``REPRO_PROFILE=1``."""
+    with jax.profiler.TraceAnnotation(name):
+        if not enabled():
+            yield
+            return
+        t0 = time.monotonic()
+        try:
+            yield
+        finally:
+            row = _COUNTS[name]
+            row["wall_us"] += (time.monotonic() - t0) * 1e6
+            row["wall_calls"] += 1
 
 
 def reset() -> None:
@@ -77,11 +106,6 @@ def reset() -> None:
 
 def snapshot() -> Dict[str, Dict[str, float]]:
     return {op: dict(row) for op, row in sorted(_COUNTS.items())}
-
-
-def dump(path: str) -> None:
-    with open(path, "w") as f:
-        json.dump({"enabled": enabled(), "ops": snapshot()}, f, indent=2)
 
 
 def maybe_attach(report: Dict[str, Any]) -> None:

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import MLAConfig, ModelConfig
+from repro.kernels import profile
 from repro.models import layers
 from repro.models.layers import apply_rope, dense, dense_init, rmsnorm, \
     rmsnorm_init, rope_angles
@@ -86,32 +87,35 @@ def chunked_attention(q, k, v, *, causal: bool = True, q_chunk: int = 1024,
         kf = jnp.repeat(k, G, axis=2) if G > 1 else k
         vf = jnp.repeat(v, G, axis=2) if G > 1 else v
         return ops.flash_attention(q, kf, vf, causal=True).astype(q.dtype)
-    if Sq <= q_chunk:
-        return _attend_dense(q, k, v, causal=causal, q_offset=q_offset,
-                             softcap=softcap).astype(q.dtype)
-    pad = (-Sq) % q_chunk
-    if pad:
-        # ragged tail (e.g. VLM prefix + text): pad queries, crop outputs —
-        # padded rows still see valid causal keys, results are discarded.
-        out = chunked_attention(
-            jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0))), k, v,
-            causal=causal, q_chunk=q_chunk, q_offset=q_offset,
-            softcap=softcap)
-        return out[:, :Sq]
-    nq = Sq // q_chunk
-    qs = q.reshape(B, nq, q_chunk, H, dh)
+    with profile.scope("repro.attention"):
+        if Sq <= q_chunk:
+            return _attend_dense(q, k, v, causal=causal, q_offset=q_offset,
+                                 softcap=softcap).astype(q.dtype)
+        pad = (-Sq) % q_chunk
+        if pad:
+            # ragged tail (e.g. VLM prefix + text): pad queries, crop
+            # outputs — padded rows still see valid causal keys, results
+            # are discarded.
+            out = chunked_attention(
+                jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0))), k, v,
+                causal=causal, q_chunk=q_chunk, q_offset=q_offset,
+                softcap=softcap)
+            return out[:, :Sq]
+        nq = Sq // q_chunk
+        qs = q.reshape(B, nq, q_chunk, H, dh)
 
-    def body(carry, inp):
-        qc, i = inp
-        off = q_offset + i * q_chunk
-        o = _attend_dense(qc, k, v, causal=causal, q_offset=off,
-                          softcap=softcap)
-        return carry, o
+        def body(carry, inp):
+            qc, i = inp
+            off = q_offset + i * q_chunk
+            o = _attend_dense(qc, k, v, causal=causal, q_offset=off,
+                              softcap=softcap)
+            return carry, o
 
-    _, outs = jax.lax.scan(
-        body, 0, (qs.transpose(1, 0, 2, 3, 4), jnp.arange(nq)))
-    dv = v.shape[-1]
-    return outs.transpose(1, 0, 2, 3, 4).reshape(B, Sq, H, dv).astype(q.dtype)
+        _, outs = jax.lax.scan(
+            body, 0, (qs.transpose(1, 0, 2, 3, 4), jnp.arange(nq)))
+        dv = v.shape[-1]
+        return outs.transpose(1, 0, 2, 3, 4).reshape(
+            B, Sq, H, dv).astype(q.dtype)
 
 
 def append_attention(q, k_cache, v_cache, q_positions) -> jax.Array:

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import ModelConfig, ShapeCell
+from repro.kernels import profile
 from repro.models import attention, layers, moe as moe_lib
 from repro.models.base import ModelBundle, SegmentDef
 from repro.models.layers import cross_entropy, dense, dense_init, \
@@ -44,6 +45,7 @@ def block_init(key, cfg: ModelConfig, *, moe_layer: bool, d_ff: int,
     return p
 
 
+@profile.scope("repro.layer")
 def block_apply(lp, carry, ctx, cfg: ModelConfig, *, moe_layer: bool,
                 q_chunk: int, dtype, ep_axis=None) -> dict:
     h = carry["h"]
@@ -268,6 +270,7 @@ def build(cfg: ModelConfig, *, q_chunk: int = 1024,
         )
         for (name, n, is_moe) in segs)
 
+    @profile.scope("repro.head_loss")
     def head_loss(params, carry, batch):
         h = carry["h"]
         labels = batch["labels"]

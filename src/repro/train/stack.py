@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import projector, quant
+from repro.kernels import profile
 from repro.models.base import ModelBundle, SegmentDef
 
 _FLOAT0 = jax.dtypes.float0
@@ -93,9 +94,10 @@ def project_leaf(g, P, side: Optional[str] = None):
     does; ``projector.project_sharded`` is the shard-aware variant)."""
     if P is None:
         return g
-    Pd = projector.maybe_dequantize(P, jnp.float32)
-    side = side or projector.galore_side(g.shape)
-    return projector.project(g.astype(jnp.float32), Pd, side)
+    with profile.scope("repro.wgrad"):
+        Pd = projector.maybe_dequantize(P, jnp.float32)
+        side = side or projector.galore_side(g.shape)
+        return projector.project(g.astype(jnp.float32), Pd, side)
 
 
 def _project_cotangents(g_lp, P_lp):

@@ -41,6 +41,7 @@ from repro.config import QGaLoreConfig, TrainConfig
 from repro.core import qgalore, quant, transform
 from repro.core.qgalore import QGaLoreState
 from repro.core.rules import as_rules
+from repro.kernels import profile
 from repro.models.base import ModelBundle
 from repro.train import stack
 
@@ -563,12 +564,13 @@ def build_train_step(bundle: ModelBundle, qcfg,
         else:
             loss, metrics, grads = grad_phase(params, proj_trees, batch)
 
-        grads, gnorm = transform.clip_by_global_norm(grads, tcfg.grad_clip,
-                                                     specs=specs)
-        new_params, new_opt, opt_metrics = tx.update(
-            grads, opt, params, lr=lr, rng=rng,
-            refresh_masks=refresh_masks, refresh=refresh, specs=specs,
-            shardings=state_shardings)
+        with profile.scope("repro.optimizer"):
+            grads, gnorm = transform.clip_by_global_norm(
+                grads, tcfg.grad_clip, specs=specs)
+            new_params, new_opt, opt_metrics = tx.update(
+                grads, opt, params, lr=lr, rng=rng,
+                refresh_masks=refresh_masks, refresh=refresh, specs=specs,
+                shardings=state_shardings)
         if dist_sims:
             opt_metrics = {**opt_metrics,
                            "sims": {**dist_sims,

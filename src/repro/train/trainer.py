@@ -31,6 +31,7 @@ from repro.config import QGaLoreConfig, TrainConfig
 from repro.core import adaptive, optimizers, qgalore
 from repro.core.rules import as_rules, group_assignment
 from repro.data.synthetic import batch_for_bundle
+from repro.kernels import profile
 from repro.models.base import ModelBundle
 from repro.train import checkpoint as ckpt_lib
 from repro.train import step as step_lib
@@ -270,15 +271,17 @@ class Trainer:
     def _run_one(self, step: int):
         if self.fault_hook is not None:
             self.fault_hook(step)             # may raise (simulated failure)
-        batch = batch_for_bundle(self.bundle, self.cell, step,
-                                 self.tcfg.seed)
-        if self._batch_sharding is not None:
-            batch = jax.device_put(batch, self._batch_sharding)
-        lr = optimizers.lr_at(step, self.tcfg)
-        rng = jax.random.fold_in(jax.random.PRNGKey(self.tcfg.seed + 17),
-                                 step)
-        masks = self.controller.masks_for_step(step) \
-            if self.controller.units else {}
+        with profile.span("repro.batch"):     # the step's inputs
+            batch = batch_for_bundle(self.bundle, self.cell, step,
+                                     self.tcfg.seed)
+            if self._batch_sharding is not None:
+                batch = jax.device_put(batch, self._batch_sharding)
+            lr = optimizers.lr_at(step, self.tcfg)
+            rng = jax.random.fold_in(
+                jax.random.PRNGKey(self.tcfg.seed + 17), step)
+        with profile.span("repro.controller"):
+            masks = self.controller.masks_for_step(step) \
+                if self.controller.units else {}
         if masks:
             # pass masks for EVERY galore leaf (False where not due) so the
             # refresh variant compiles exactly once
@@ -286,8 +289,9 @@ class Trainer:
                 i: jnp.asarray(masks[i]) if i in masks
                 else jnp.zeros((s.nbatch,), bool)
                 for i, s in enumerate(self.specs) if s.galore}
-            state, metrics, opt_metrics = self._step_refresh(
-                self.state, batch, lr, rng, jmasks)
+            with profile.span("repro.dispatch"):
+                state, metrics, opt_metrics = self._step_refresh(
+                    self.state, batch, lr, rng, jmasks)
             sims = {k: np.asarray(v)
                     for k, v in opt_metrics.get("sims", {}).items()}
             ratios = {k: np.asarray(v)
@@ -295,7 +299,9 @@ class Trainer:
             self.controller.observe(step, masks, sims, ratios)
             decisions = self.controller.take_rank_decisions()
         else:
-            state, metrics, _ = self._step_normal(self.state, batch, lr, rng)
+            with profile.span("repro.dispatch"):
+                state, metrics, _ = self._step_normal(self.state, batch, lr,
+                                                      rng)
             decisions = []
         self.state = state
         if decisions:

@@ -10,6 +10,7 @@ not fit the scoped VMEM. The kernels are called with an explicit
 here.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +21,8 @@ from repro.core.quant import QTensor
 from repro.kernels import ops
 
 D, FF, FF_PAD, VOCAB, RANK, BLOCK = 2048, 5461, 5632, 32000, 128, 256
+BENCH_TESTS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "bench")
 
 
 @pytest.fixture(scope="module")
@@ -122,3 +125,33 @@ def test_fused_update_compiles(one_chip, side, m, n_pad, n):
     text = _compiled_text(update, w, low, low, low, proj, scalar, scalar,
                           key)
     assert text.count("tpu_custom_call") >= 1
+
+
+def test_plain_step_keeps_kernel_names_and_carries_scopes(one_chip,
+                                                          monkeypatch):
+    """The whole plain training step of the benchmark's tiny cell on the
+    kernels: every Pallas call keeps an instruction name that the
+    benchmark's kernel readers pick, and the device scopes reach the XLA
+    ops' ``op_name`` metadata (and never a kernel's instruction name)."""
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "pallas-tpu")
+    monkeypatch.syspath_prepend(BENCH_TESTS)
+    import tiny_step
+    from bench import scopes, spec, trace
+    from repro.kernels import profile
+
+    fn, args = tiny_step.plain_step(one_chip)
+    text = fn.lower(*args).compile().as_text()
+    calls = [trace.Event(line.strip().removeprefix("ROOT "), 0, 1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    i8 = trace.pick(calls, spec.reader("int8_matmul.ms_per_step").PATTERNS)
+    fu = trace.pick(calls, spec.reader("fused_update.ms_per_step").PATTERNS)
+    assert calls and len(i8) + len(fu) == len(calls), \
+        sorted({e.kind for e in calls})
+    assert {re.sub(r"^.*(int8_matmul(_t)?).*$", r"\1", e.kind)
+            for e in i8} == {"int8_matmul", "int8_matmul_t"}
+    assert fu
+    assert not any(s in e.op for e in calls for s in profile.SCOPES)
+    names = scopes.op_names(text)
+    assert set(profile.SCOPES) <= {scopes.scope_of(n)
+                                   for n in names.values()}

@@ -147,6 +147,40 @@ class TestStraggler:
         assert tr.stragglers.events
 
 
+class TestSpans:
+    def test_run_marks_host_spans(self, tmp_path, monkeypatch):
+        """The parts of ``_run_one`` that prepare a step (its inputs, the
+        controller's masks, the dispatch) are host spans: written into a
+        profiler trace, and counted by the wall-clock accumulator under
+        ``REPRO_PROFILE=1``."""
+        from repro.kernels import profile
+        monkeypatch.setenv("REPRO_PROFILE", "1")
+        profile.reset()
+        tr = make_trainer(steps=2)
+        jax.profiler.start_trace(str(tmp_path / "trace"))
+        try:
+            tr.run()
+        finally:
+            jax.profiler.stop_trace()
+        calls = {k: v["wall_calls"] for k, v in profile.snapshot().items()
+                 if k.startswith("repro.")}
+        profile.reset()
+        assert calls == {"repro.batch": 2, "repro.controller": 2,
+                         "repro.dispatch": 2}
+        import glob
+        path, = glob.glob(str(tmp_path / "trace" / "plugins" / "profile"
+                              / "*" / "*.xplane.pb"))
+        names = {e.name for plane in
+                 jax.profiler.ProfileData.from_file(path).planes
+                 for line in plane.lines for e in line.events}
+        assert set(calls) <= names
+
+    def test_undeclared_scope_is_refused(self):
+        from repro.kernels import profile
+        with pytest.raises(ValueError, match="undeclared"):
+            profile.scope("repro.nowhere")
+
+
 class TestImplParity:
     def test_fused_and_simple_same_losses(self):
         h1 = make_trainer(steps=6, impl="fused", seed=3).run()
