@@ -24,10 +24,21 @@ sweep refuses to run there unless ``--backend`` names a Pallas backend
 explicitly (CI smoke uses ``pallas-interpret``; real numbers come from
 ``pallas-tpu`` on the accelerator).
 
+``--train CONFIG TRAFFIC`` sweeps the INT8 matmuls of one training step
+instead: M is the rows per update of the traffic file (``batch * seq``),
+(K, N) each quantized weight of the configuration file (the benchmark's
+``bench/configs`` and ``bench/traffic`` layout), activations bf16 as the
+step runs them. The wrappers look tiles up by ``(M, K)`` alone, so each
+bucket keeps the tile set with the least time summed over its problems,
+each weighted by how many of the model's weights have that shape.
+
     PYTHONPATH=src:. python benchmarks/autotune_blocks.py \
         --backend pallas-interpret --smoke --out BENCH_autotune.json
     REPRO_REGEN_AUTOTUNE=1 PYTHONPATH=src:. \
         python benchmarks/autotune_blocks.py        # on-TPU refresh
+    REPRO_REGEN_AUTOTUNE=1 PYTHONPATH=src:. \
+        python benchmarks/autotune_blocks.py --train \
+        bench/configs/internlm2-1.8b.json bench/traffic/pretrain-s256.json
 """
 from __future__ import annotations
 
@@ -35,7 +46,7 @@ import argparse
 import json
 import os
 import time
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -68,6 +79,15 @@ CANDIDATES = {
                              for bm in (128, 256, 512)],
 }
 
+# The training step's shapes (M in the thousands): taller row tiles and
+# wider column / contraction tiles, each candidate compiled and timed.
+TRAIN_CANDIDATES = {
+    op: [{"bm": bm, "bn": bn, "bk": bk}
+         for bm in (256, 512, 1024) for bn in (512, 1024, 2048)
+         for bk in (512, 1024, 2048)]
+    for op in ("int8_matmul", "int8_matmul_t")
+}
+
 SMOKE_CANDIDATES = {
     "int8_matmul": [{"bm": bm, "bn": 256, "bk": 128} for bm in (8, 64)],
     "int8_matmul_t": [{"bm": bm, "bn": 256, "bk": 64} for bm in (8, 64)],
@@ -98,40 +118,74 @@ def _effective_i8(op: str, M: int, K: int, n_pad: int, qblock: int,
 
 
 def sweep_int8(shapes, backend: str, *, iters: int, repeats: int,
-               qblock: int, rows: List[dict]) -> None:
+               qblock: int, rows: List[dict], dtype=jnp.float32,
+               calls: Optional[Dict[Tuple[int, int, int], int]] = None
+               ) -> None:
+    """Time every distinct effective tile set of both INT8 kernels on each
+    (M, K, N) through the training path's wrappers. The wrappers query
+    the table with ``(M, K)``, so per op and ``(M, K)`` bucket the
+    candidate with the least time summed over the bucket's problems wins,
+    each problem weighted by ``calls`` (default 1). Weights go in as
+    arguments (not baked-in constants); a tile set the compiler refuses
+    is reported, and rules its candidates out of that bucket."""
     key = jax.random.PRNGKey(0)
+    # (op, bucketed (M, K)) -> problem -> candidate label -> (us, cand)
+    timed: Dict[tuple, Dict[tuple, Dict[str, tuple]]] = {}
     for (M, K, N) in shapes:
-        x = jax.random.normal(key, (M, K), jnp.float32)
+        x = jax.random.normal(key, (M, K), dtype)
         w = jax.random.normal(jax.random.fold_in(key, 1), (K, N)) * 0.1
         qt = quantize_blockwise(w, bits=8, block=qblock, symmetric=True)
         n_pad = qt.q.shape[-1]
-        g = jax.random.normal(jax.random.fold_in(key, 2),
-                              (M, n_pad), jnp.float32)
-        for op, run in (
-            ("int8_matmul", lambda c: jax.jit(
-                lambda a: ops.int8_matmul(a, qt, backend=backend))),
-            ("int8_matmul_t", lambda c: jax.jit(
-                lambda a: ops._i8t_call(backend, a, qt.q, qt.scale,
-                                        qt.block))),
+        g = jax.random.normal(jax.random.fold_in(key, 2), (M, n_pad), dtype)
+        for op, operand, call in (
+            ("int8_matmul", x, lambda a, q, s: ops._i8_call(
+                backend, a, q, s, qt.block, n_pad)),
+            ("int8_matmul_t", g, lambda a, q, s: ops._i8t_call(
+                backend, a, q, s, qt.block)),
         ):
-            operand = x if op == "int8_matmul" else g
             shape_key = (M, K)           # what the wrapper queries with
-            seen: Dict[Tuple[int, ...], Dict[str, int]] = {}
+            seen: Dict[Tuple[int, ...], List[Dict[str, int]]] = {}
             for cand in CANDIDATES[op]:
                 eff = _effective_i8(op, M, K, n_pad, qt.block, cand)
-                seen.setdefault(eff, cand)
-            timings = []
-            for cand in seen.values():
-                dispatch.register_tuned(op, backend, shape_key, cand,
+                seen.setdefault(eff, []).append(cand)
+            bucket = (op, tuple(dispatch._bucket(d) for d in shape_key))
+            got = timed.setdefault(bucket, {}).setdefault((M, K, N), {})
+            for cands in seen.values():
+                dispatch.register_tuned(op, backend, shape_key, cands[0],
                                         str(operand.dtype))
-                us = _bestof(run(cand), (operand,), iters=iters,
-                             repeats=repeats)
-                timings.append((us, cand))
-            us, best = min(timings, key=lambda t: t[0])
-            rows.append(_row(op, backend, shape_key, operand.dtype, best,
-                             us, (M, K, N)))
-            emit(f"autotune/{op}", us,
-                 f"M={M};K={K};N={N};blocks={_fmt(best)};backend={backend}")
+                try:
+                    us = _bestof(jax.jit(lambda a, q, s: call(a, q, s)),
+                                 (operand, qt.q, qt.scale), iters=iters,
+                                 repeats=repeats)
+                except Exception as e:  # noqa: BLE001 — compiler refusal
+                    emit(f"autotune/{op}/refused", 0.0,
+                         f"M={M};K={K};N={N};blocks={_fmt(cands[0])};"
+                         f"{str(e).splitlines()[0][:120]}")
+                    continue
+                emit(f"autotune/{op}/candidate", us,
+                     f"M={M};K={K};N={N};blocks={_fmt(cands[0])}")
+                for cand in cands:
+                    got[_fmt(cand)] = (us, cand)
+
+    for (op, bshape), problems in timed.items():
+        usable = set.intersection(*(set(t) for t in problems.values()))
+        if not usable:
+            continue
+
+        def weighted(label):
+            return sum((calls or {}).get(p, 1) * t[label][0]
+                       for p, t in problems.items())
+
+        label = min(sorted(usable), key=weighted)
+        # the entry records the problem that weighs most at the winner
+        top = max(problems, key=lambda p: (calls or {}).get(p, 1)
+                  * problems[p][label][0])
+        us, best = problems[top][label]
+        rows.append(_row(op, backend, top[:2], jnp.dtype(dtype), best, us,
+                         top))
+        emit(f"autotune/{op}", weighted(label),
+             f"M={top[0]};K={top[1]};bucket={bshape[0]}x{bshape[1]};"
+             f"problems={len(problems)};blocks={label};backend={backend}")
 
 
 def sweep_fused(weight_shapes, backend: str, *, iters: int, repeats: int,
@@ -208,6 +262,28 @@ def shape_grid(batch: int, prompt: int):
     return sorted(shapes), sorted(weights)
 
 
+def train_shape_grid(config_path: str, traffic_path: str):
+    """(M, K, N) of every INT8 matmul of one training step of a benchmark
+    cell, and how many of the model's weights have each: M = ``batch *
+    seq`` of the traffic, (K, N) the configuration's attention projections
+    (q, k, v, o), FFN (gate, up, down), each in every layer, and the
+    untied head once."""
+    with open(config_path) as f:
+        cfg = json.load(f)["model"]
+    with open(traffic_path) as f:
+        traffic = json.load(f)
+    M = traffic["batch"] * traffic["seq"]
+    d, ff, v = cfg["d_model"], cfg["d_ff"], cfg["vocab_size"]
+    hd = cfg["head_dim"] or d // cfg["num_heads"]
+    per_layer = [(d, cfg["num_heads"] * hd), (d, cfg["num_kv_heads"] * hd),
+                 (d, cfg["num_kv_heads"] * hd), (cfg["num_heads"] * hd, d),
+                 (d, ff), (d, ff), (ff, d)]
+    calls: Dict[Tuple[int, int, int], int] = {(M, d, v): 1}
+    for K, N in per_layer:
+        calls[(M, K, N)] = calls.get((M, K, N), 0) + cfg["num_layers"]
+    return sorted(calls), calls
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--backend", default=None,
@@ -220,6 +296,9 @@ def main(argv=None):
     ap.add_argument("--repeats", type=int, default=3)
     ap.add_argument("--smoke", action="store_true",
                     help="tiny shapes + 2 candidates/op (CI artifact run)")
+    ap.add_argument("--train", nargs=2, metavar=("CONFIG", "TRAFFIC"),
+                    help="sweep the INT8 matmuls of one training step of "
+                         "this configuration and traffic file (bf16)")
     ap.add_argument("--out", default="BENCH_autotune.json")
     ap.add_argument("--table", default=None,
                     help="merge measured entries into this table file")
@@ -236,6 +315,7 @@ def main(argv=None):
                        "entries": []}, f, indent=2)
         return None
 
+    dtype, calls = jnp.float32, None
     if args.smoke:
         CANDIDATES.clear()
         CANDIDATES.update(SMOKE_CANDIDATES)
@@ -243,6 +323,13 @@ def main(argv=None):
         weights = [(64, 128)]
         qblock, iters, repeats = 64, 1, 1
         rank = 16
+    elif args.train:
+        CANDIDATES.clear()
+        CANDIDATES.update(TRAIN_CANDIDATES)
+        (shapes, calls), weights = train_shape_grid(*args.train), []
+        qblock, iters, repeats = 256, args.iters, args.repeats
+        rank = args.rank
+        dtype = jnp.bfloat16
     else:
         shapes, weights = shape_grid(args.batch, args.prompt)
         qblock, iters, repeats = 256, args.iters, args.repeats
@@ -251,7 +338,7 @@ def main(argv=None):
     rows: List[dict] = []
     with profile.span("autotune/sweep"):
         sweep_int8(shapes, backend, iters=iters, repeats=repeats,
-                   qblock=qblock, rows=rows)
+                   qblock=qblock, rows=rows, dtype=dtype, calls=calls)
         sweep_fused(weights, backend, iters=iters, repeats=repeats,
                     rank=rank, qblock=qblock, rows=rows)
     dispatch._RUNTIME_TABLE.clear()      # drop sweep candidates
@@ -267,7 +354,8 @@ def main(argv=None):
 
     report = {
         "meta": {"backend": backend, "platform": dispatch.platform(),
-                 "smoke": args.smoke, "batch": args.batch,
+                 "smoke": args.smoke, "train": args.train,
+                 "batch": args.batch,
                  "prompt": args.prompt, "iters": iters,
                  "repeats": repeats, "n_shapes": len(shapes)},
         "entries": entries,

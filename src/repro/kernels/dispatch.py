@@ -31,7 +31,8 @@ Block-size autotune table
 :func:`tuned_blocks` returns the block-size kwargs for a (op, shape, dtype,
 backend) query. Shapes are bucketed to the next power of two so the table
 stays small; exact entries win over bucketed entries, which win over the
-per-op defaults.
+per-op defaults. :func:`tuned_lookup` also says where the tiles came from
+(``"measured"``, ``"seed"``, ``"runtime"`` or ``"default"``).
 
 The table is PERSISTED: entries live in ``autotune_table.json`` next to
 this module (override the path with ``REPRO_AUTOTUNE_TABLE``) and are
@@ -153,6 +154,7 @@ _TABLE_ENV = "REPRO_AUTOTUNE_TABLE"
 _TABLE_FILE = os.path.join(os.path.dirname(__file__), "autotune_table.json")
 
 _RUNTIME_TABLE: Dict[_Key, Dict[str, int]] = {}
+_Hit = Tuple[Dict[str, int], str]           # (block kwargs, source)
 
 
 def table_path() -> str:
@@ -165,14 +167,15 @@ def _entry_key(e: Dict[str, Any]) -> _Key:
 
 
 @functools.lru_cache(maxsize=8)
-def _load_table(path: str) -> Dict[_Key, Dict[str, int]]:
+def _load_table(path: str) -> Dict[_Key, _Hit]:
     if not os.path.exists(path):
         return {}
     with open(path) as f:
         doc = json.load(f)
-    table: Dict[_Key, Dict[str, int]] = {}
+    table: Dict[_Key, _Hit] = {}
     for e in doc.get("entries", ()):
-        table[_entry_key(e)] = {k: int(v) for k, v in e["blocks"].items()}
+        table[_entry_key(e)] = ({k: int(v) for k, v in e["blocks"].items()},
+                                e.get("source", "seed"))
     return table
 
 
@@ -263,24 +266,33 @@ def pick_tile(dim: int, request: int, multiple_of: int = 8) -> int:
     return max(multiple_of, min(max(request, multiple_of), need))
 
 
-def tuned_blocks(op: str, shape: Tuple[int, ...],
-                 dtype: str = "", backend: Optional[str] = None
-                 ) -> Dict[str, int]:
-    """Block-size kwargs for ``op`` on a problem of ``shape``.
+def tuned_lookup(op: str, shape: Tuple[int, ...], dtype: str = "",
+                 backend: Optional[str] = None) -> _Hit:
+    """(block-size kwargs, source) for ``op`` on a problem of ``shape``.
 
     ``shape`` is the op's 2-D problem footprint (e.g. the weight matrix
-    (M, N) for the fused update). Lookup order: in-process registrations
-    (:func:`register_tuned`) → the persisted table (exact (bucketed
-    shape, dtype), then (bucketed shape, any dtype)) → per-op defaults.
+    (M, N) for the fused update, (rows, K) for the INT8 matmuls). Lookup
+    order: in-process registrations (:func:`register_tuned`, source
+    ``"runtime"``) → the persisted table (exact (bucketed shape, dtype),
+    then (bucketed shape, any dtype); the entry's ``source``) → per-op
+    defaults (``"default"``).
     """
     backend = backend or default_backend(op)
     bshape = tuple(_bucket(int(d)) for d in shape)
     table = _load_table(table_path())
     for dt in (dtype, ""):
         key = (op, backend, bshape, dt)
-        hit = _RUNTIME_TABLE.get(key)
-        if hit is None:
-            hit = table.get(key)
-        if hit is not None:
-            return dict(hit)
-    return dict(_DEFAULT_BLOCKS.get(op, {}))
+        if key in _RUNTIME_TABLE:
+            return dict(_RUNTIME_TABLE[key]), "runtime"
+        if key in table:
+            blocks, source = table[key]
+            return dict(blocks), source
+    return dict(_DEFAULT_BLOCKS.get(op, {})), "default"
+
+
+def tuned_blocks(op: str, shape: Tuple[int, ...],
+                 dtype: str = "", backend: Optional[str] = None
+                 ) -> Dict[str, int]:
+    """Block-size kwargs for ``op`` on a problem of ``shape``
+    (:func:`tuned_lookup` without the source)."""
+    return tuned_lookup(op, shape, dtype, backend)[0]

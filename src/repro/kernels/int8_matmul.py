@@ -3,9 +3,15 @@ dequant scale FUSED into the matmul — no dequantized weight tile in VMEM.
 
 TPU adaptation of the paper's INT8 GEMM (bitsandbytes on CUDA): v5e has no
 INT8 training GEMM, so the win is HBM traffic — weights stream at 1 byte
-instead of 2 and feed the MXU as raw codes. Block layout matches the
-training representation: scales per (row, 256-col group), so the kernel
-consumes optimizer output with zero relayout.
+instead of 2 and are widened in VMEM, tile by tile. Block layout matches
+the training representation: scales per (row, 256-col group), so the
+kernel consumes optimizer output with zero relayout.
+
+MXU operands are f32 for bf16 and f32 activations alike: the activation
+tile and the INT8 codes (exact in f32) widen in VMEM, and the f32
+accumulator lives across the contraction loop. On a v5e at the training
+step's shapes, bf16 operands ran within 1% of these and gave the same
+numbers (PERF.md §6): the tiles, not the operand format, set the speed.
 
 Two orientations over the SAME stored blocks, with the scale applied on
 opposite sides of the dot (the scale axis is the weight's ROW axis K times
@@ -34,7 +40,11 @@ the same association order.
 ``int8_matmul`` grid: (M/BM, N/BN, K/BK), K innermost; f32 accumulator
 lives in a VMEM scratch across the K loop. BN is a multiple of the quant
 block (256) so each weight tile owns whole scale groups. ``int8_matmul_t``
-walks (M/BM, K/BK, N/BN) with N innermost.
+walks (M/BM, K/BK, N/BN) with N innermost. Tiles come from
+``dispatch.tuned_blocks``: the training step's row and contraction
+buckets (M 8192) have measured entries in ``autotune_table.json``; a tile
+set whose VMEM footprint passes the default scoped limit raises the limit
+for its call (:func:`_compiler_params`).
 """
 from __future__ import annotations
 
@@ -44,6 +54,27 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+# Mosaic's default scoped VMEM on v5e; tile sets above it raise the limit.
+_DEFAULT_VMEM = 16 * 2**20
+_MAX_VMEM = 100 * 2**20
+
+
+def _compiler_params(bm: int, bn: int, bk: int, a_cols: int, out_cols: int,
+                     act_bytes: int):
+    """``CompilerParams`` raising the scoped-VMEM limit when the tiles'
+    footprint estimate passes the default, else None (default limit).
+
+    Counted: double-buffered activation, code, scale and output blocks;
+    the f32 accumulator; the f32 activation tile, one f32 operand per
+    group beside it, and the codes widened to f32."""
+    est = (2 * (bm * a_cols * act_bytes + bk * bn + bk * (bn // 128) * 4
+                + bm * out_cols * act_bytes)
+           + bm * out_cols * 4 + bk * bn * 4 + bm * a_cols * 12)
+    if est <= _DEFAULT_VMEM * 3 // 4:
+        return None
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=min(_MAX_VMEM, max(2 * est, _DEFAULT_VMEM)))
 
 
 def _scale_tiles(scale, bn: int, block: int):
@@ -93,6 +124,7 @@ def int8_matmul(x, q, scale, *, block: int = 256, bm: int = 128,
     assert K == Kq and N % block == 0 and bn % block == 0
     bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
     grid = (M // bm, N // bn, K // bk)
+    params = _compiler_params(bm, bn, bk, bk, bn, x.dtype.itemsize)
     return pl.pallas_call(
         functools.partial(_kernel, block=block, n_k=grid[2]),
         grid=grid,
@@ -104,6 +136,7 @@ def int8_matmul(x, q, scale, *, block: int = 256, bm: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=params,
         interpret=interpret,
     )(x, q, _scale_tiles(scale, bn, block))
 
@@ -118,8 +151,8 @@ def _kernel_t(g_ref, q_ref, s_ref, o_ref, acc_ref, *, block: int, n_n: int):
     s = s_ref[...]                                # (BN // block, BK)
     BK, BN = q.shape
     # Contraction runs along N — the quant axis — so the scale applies
-    # ONCE per group on the (BM, BK) accumulator: a true epilogue, raw
-    # INT8 codes feed the MXU.
+    # ONCE per group on the (BM, BK) accumulator: a true epilogue on the
+    # raw-code partial dots.
     for gg in range(BN // block):
         sl = slice(gg * block, (gg + 1) * block)
         pdot = jax.lax.dot_general(
@@ -149,6 +182,7 @@ def int8_matmul_t(g, q, scale, *, block: int = 256, bm: int = 128,
     assert N == Nq and N % block == 0 and bn % block == 0
     bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
     grid = (M // bm, K // bk, N // bn)
+    params = _compiler_params(bm, bn, bk, bn, bk, g.dtype.itemsize)
     return pl.pallas_call(
         functools.partial(_kernel_t, block=block, n_n=grid[2]),
         grid=grid,
@@ -160,5 +194,6 @@ def int8_matmul_t(g, q, scale, *, block: int = 256, bm: int = 128,
         out_specs=pl.BlockSpec((bm, bk), lambda i, k, n: (i, k)),
         out_shape=jax.ShapeDtypeStruct((M, K), g.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32)],
+        compiler_params=params,
         interpret=interpret,
     )(g, q, _scale_tiles(scale, bn, block))

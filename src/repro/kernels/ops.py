@@ -132,6 +132,16 @@ def _i8_bytes(M: int, K: int, N: int, block: int, x_itemsize: int) -> int:
             + K * (N // block) * 4 + M * N * 4)        # scales + f32 out
 
 
+def _i8_meta(name: str, shape, tiles: str) -> dict:
+    """Profile meta of one INT8 matmul call: the problem, the backend and,
+    for a Pallas kernel, where its tiles came from
+    (``dispatch.tuned_lookup``)."""
+    meta = {"shape": list(shape), "backend": name}
+    if name != "ref":
+        meta["tiles"] = tiles
+    return meta
+
+
 def int8_matmul(x, qt: QTensor, *, backend=None, interpret=None):
     """``x (..., K) @ dequant(qt (K, N))`` — QTensor must be symmetric INT8.
 
@@ -144,13 +154,13 @@ def int8_matmul(x, qt: QTensor, *, backend=None, interpret=None):
     K = x.shape[-1]
     xf = x.reshape(-1, K)
     N = qt.q.shape[-1]
-    blocks = dispatch.tuned_blocks("int8_matmul", (xf.shape[0], K),
-                                   str(x.dtype), backend=name)
+    blocks, tiles = dispatch.tuned_lookup("int8_matmul", (xf.shape[0], K),
+                                          str(x.dtype), backend=name)
     profile.record("int8_matmul",
                    nbytes=_i8_bytes(xf.shape[0], K, N, qt.block,
                                     x.dtype.itemsize),
                    flops=2 * xf.shape[0] * K * N,
-                   meta={"shape": [xf.shape[0], K, N], "backend": name})
+                   meta=_i8_meta(name, (xf.shape[0], K, N), tiles))
     # pick the row tile from the TRUE M, then pad M up to it (a 1-row
     # decode matmul pads to one 8-row tile, not a 128-row boundary)
     blocks["bm"] = dispatch.pick_tile(xf.shape[0], blocks["bm"])
@@ -188,17 +198,16 @@ def _deq_cropped(q, scale, block: int, orig: int):
 
 def _i8_call(name: str, x2, q, scale, block: int, orig: int):
     """x2 (M, K) @ deq(q (K, Np))[:, :orig] → (M, orig)."""
+    shape = (x2.shape[0], x2.shape[1], q.shape[1])
+    blocks, tiles = dispatch.tuned_lookup("int8_matmul", x2.shape,
+                                          str(x2.dtype), backend=name)
     profile.record("quantized_dense/fwd",
-                   nbytes=_i8_bytes(x2.shape[0], x2.shape[1], q.shape[1],
-                                    block, x2.dtype.itemsize),
-                   flops=2 * x2.shape[0] * x2.shape[1] * q.shape[1],
-                   meta={"shape": [x2.shape[0], x2.shape[1], q.shape[1]],
-                         "backend": name})
+                   nbytes=_i8_bytes(*shape, block, x2.dtype.itemsize),
+                   flops=2 * shape[0] * shape[1] * shape[2],
+                   meta=_i8_meta(name, shape, tiles))
     if name == "ref":
         return x2.astype(jnp.float32) @ _deq_cropped(q, scale, block, orig)
     fn = dispatch.resolve("int8_matmul", name)[1]
-    blocks = dispatch.tuned_blocks("int8_matmul", x2.shape,
-                                   str(x2.dtype), backend=name)
     # row tile from the TRUE M, then pad M up to the tile (tail-block fix)
     blocks["bm"] = dispatch.pick_tile(x2.shape[0], blocks["bm"])
     x2, M = _pad_to(x2, 0, blocks["bm"])
@@ -209,12 +218,13 @@ def _i8_call(name: str, x2, q, scale, block: int, orig: int):
 
 def _i8t_call(name: str, g2, q, scale, block: int):
     """g2 (M, orig) @ deq(q (K, Np))[:, :orig]^T → (M, K)."""
+    shape = (g2.shape[0], q.shape[0], q.shape[1])
+    blocks, tiles = dispatch.tuned_lookup("int8_matmul_t", shape[:2],
+                                          str(g2.dtype), backend=name)
     profile.record("quantized_dense/dx",
-                   nbytes=_i8_bytes(g2.shape[0], q.shape[0], q.shape[1],
-                                    block, g2.dtype.itemsize),
-                   flops=2 * g2.shape[0] * q.shape[0] * q.shape[1],
-                   meta={"shape": [g2.shape[0], q.shape[0], q.shape[1]],
-                         "backend": name})
+                   nbytes=_i8_bytes(*shape, block, g2.dtype.itemsize),
+                   flops=2 * shape[0] * shape[1] * shape[2],
+                   meta=_i8_meta(name, shape, tiles))
     if name == "ref":
         w = _deq_cropped(q, scale, block, g2.shape[-1])
         return g2.astype(jnp.float32) @ w.T
@@ -222,9 +232,6 @@ def _i8t_call(name: str, g2, q, scale, block: int):
     pad = q.shape[1] - g2.shape[-1]
     if pad:  # padded weight columns dequantize to zero — zero-pad g to match
         g2 = jnp.pad(g2, ((0, 0), (0, pad)))
-    blocks = dispatch.tuned_blocks("int8_matmul_t",
-                                   (g2.shape[0], q.shape[0]),
-                                   str(g2.dtype), backend=name)
     blocks["bm"] = dispatch.pick_tile(g2.shape[0], blocks["bm"])
     g2, M = _pad_to(g2, 0, blocks["bm"])
     blocks["bn"] = dispatch.fit_block(q.shape[1], blocks["bn"], block)

@@ -9,7 +9,9 @@ What CAN be recorded cheaply and without perturbing the compiled graph:
   while tracing, logging how many times each kernel op is baked into a
   compiled program and the HBM bytes / FLOPs one execution of that call
   moves. Re-traces count again (that is itself a useful signal: an
-  unexpected recount means shape churn → recompiles). Only under
+  unexpected recount means shape churn → recompiles). Each string in a
+  call's ``meta`` is tallied too (``meta_calls``, e.g. how many INT8
+  matmul calls run on measured tiles). Only under
   ``REPRO_PROFILE=1``.
 * **device scopes** — :func:`scope` is ``jax.named_scope`` for one of the
   names in :data:`SCOPES`. It sets the ``op_name`` metadata of every HLO
@@ -65,7 +67,8 @@ def record(op: str, *, nbytes: int = 0, flops: int = 0,
            meta: Optional[Dict[str, Any]] = None) -> None:
     """Trace-time hook: count one baked-in call of ``op`` and the HBM
     bytes / FLOPs a single execution of it moves. ``meta`` (e.g. the
-    problem shape) is kept from the most recent call."""
+    problem shape) is kept from the most recent call; its string values
+    are tallied over all calls as ``meta_calls["key=value"]``."""
     if not enabled():
         return
     row = _COUNTS[op]
@@ -74,6 +77,10 @@ def record(op: str, *, nbytes: int = 0, flops: int = 0,
     row["flops_per_call"] = int(flops)
     if meta:
         row["meta"] = dict(meta)
+        tally = row.setdefault("meta_calls", {})
+        for k, v in meta.items():
+            if isinstance(v, str):
+                tally[f"{k}={v}"] = tally.get(f"{k}={v}", 0) + 1
 
 
 def scope(name: str):

@@ -117,6 +117,60 @@ class TestTableRoundtrip:
         assert all(e["source"] in ("seed", "measured") for e in entries)
 
 
+class TestTrainingShapeEntries:
+    """The committed table answers the training step's INT8 matmuls (the
+    benchmark's InternLM2-1.8B cell: 8192 rows per update) with measured
+    bf16 tiles, keyed by the bucketed (rows, K) the wrappers query."""
+
+    @pytest.fixture(autouse=True)
+    def _committed_table(self, monkeypatch):
+        monkeypatch.delenv("REPRO_AUTOTUNE_TABLE", raising=False)
+        dispatch.reload_table()
+
+    @pytest.mark.parametrize("K,N", [(2048, 2048), (2048, 1024),
+                                     (2048, 8192), (8192, 2048),
+                                     (2048, 92553)])
+    @pytest.mark.parametrize("op", ["int8_matmul", "int8_matmul_t"])
+    def test_measured_entry(self, op, K, N):
+        """Each training matmul finds its bucket's measured entry, and the
+        wrapper's clamps leave it a tile that divides the padded problem:
+        the head's 92,672 columns (181 x 512) take 512, never the whole
+        width."""
+        blocks, source = dispatch.tuned_lookup(
+            op, (8192, K), "bfloat16", backend="pallas-tpu")
+        assert source == "measured"
+        entry = next(e for e in dispatch.load_table_entries(
+            dispatch._TABLE_FILE) if e["op"] == op and e["shape"] == [8192, K])
+        assert blocks == entry["blocks"]
+        assert entry["us"] > 0 and entry["problem"][:2] == [8192, K]
+        n_pad = -(-N // 256) * 256
+        bn = dispatch.fit_block(n_pad, blocks["bn"], 256)
+        assert n_pad % bn == 0 and bn <= blocks["bn"]
+        assert bn == (512 if N == 92553 else min(blocks["bn"], n_pad))
+        assert dispatch.fit_block(K, blocks["bk"], 128) == blocks["bk"]
+
+    def test_other_dtype_and_shape_fall_back(self):
+        """f32 activations and serving's decode rows do not reach them."""
+        for shape, dtype in (((8192, 2048), "float32"),
+                             ((8, 2048), "bfloat16")):
+            assert dispatch.tuned_lookup(
+                "int8_matmul", shape, dtype,
+                backend="pallas-tpu")[1] == "default"
+
+    def test_runtime_registration_wins_over_measured(self):
+        """A sweep candidate registered at the key the wrapper queries is
+        what the wrapper runs, over the committed measured entry."""
+        cand = {"bm": 256, "bn": 512, "bk": 512}
+        dispatch.register_tuned("int8_matmul", "pallas-tpu", (8192, 2048),
+                                cand, "bfloat16")
+        try:
+            assert dispatch.tuned_lookup(
+                "int8_matmul", (8192, 2048), "bfloat16",
+                backend="pallas-tpu") == (cand, "runtime")
+        finally:
+            dispatch._RUNTIME_TABLE.clear()
+
+
 class TestTunedBlocksReachKernel:
     def test_wrapper_honors_runtime_entry(self, monkeypatch):
         """A registered entry flows through the ops wrapper into a

@@ -13,7 +13,7 @@ from repro.kernels import ops, ref
 from repro.kernels.blockwise_quant import blockwise_quant
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.int4_matmul import int4_matmul
-from repro.kernels.int8_matmul import int8_matmul
+from repro.kernels.int8_matmul import int8_matmul, int8_matmul_t
 from repro.kernels.sr_requant import sr_requant
 
 
@@ -46,6 +46,52 @@ class TestInt8Matmul:
         np.testing.assert_allclose(
             np.asarray(got).reshape(-1, 512), np.asarray(want),
             rtol=5e-2, atol=5e-2)
+
+
+class TestInt8TrainingTiles:
+    """Both INT8 kernels at the training step's tile shapes: ``bn`` 1024
+    (four scale groups per tile), ``bk`` 1024 and 2048, under bf16 and f32
+    activations. Weights at 1/sqrt(K), so outputs are O(1) as in a
+    model."""
+
+    M, K, N = 64, 2048, 2048
+
+    def _operands(self, op, dtype):
+        w = _rand(31, (self.K, self.N), scale=self.K ** -0.5)
+        qt = quant.quantize_blockwise(w, bits=8, symmetric=True)
+        rows = self.K if op == "fwd" else self.N
+        return _rand(30, (self.M, rows), dtype), qt
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("bk", [1024, 2048])
+    @pytest.mark.parametrize("op", ["fwd", "dx"])
+    def test_matches_ref(self, op, bk, dtype):
+        a, qt = self._operands(op, dtype)
+        kern, oracle = ((int8_matmul, ref.int8_matmul_ref) if op == "fwd"
+                        else (int8_matmul_t, ref.int8_matmul_t_ref))
+        got = kern(a, qt.q, qt.scale, block=qt.block, bm=self.M, bn=1024,
+                   bk=bk, interpret=True)
+        assert got.dtype == dtype
+        want = oracle(a, qt.q, qt.scale, qt.block)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=2e-2, atol=2e-2)
+
+    def test_dx_bf16_products_exact(self):
+        """Products of bf16 ``g`` and INT8 codes are exact in f32, so
+        ``int8_matmul_t`` meets the f32 oracle at rtol 1e-4 up to the
+        output's one rounding to bf16: each output is the bf16 rounding of
+        a value within 1e-4 of the oracle's."""
+        g, qt = self._operands("dx", jnp.bfloat16)
+        got = int8_matmul_t(g, qt.q, qt.scale, block=qt.block, bm=self.M,
+                            bn=1024, bk=1024, interpret=True)
+        want = np.asarray(ref.int8_matmul_t_ref(g, qt.q, qt.scale,
+                                                qt.block))
+        tol = 1e-4 * np.abs(want) + 1e-4 * np.abs(want).max()
+        lo = jnp.asarray(want - tol).astype(jnp.bfloat16)
+        hi = jnp.asarray(want + tol).astype(jnp.bfloat16)
+        assert bool(jnp.all((got >= lo) & (got <= hi)))
 
 
 class TestInt4Matmul:

@@ -156,6 +156,30 @@ class TestDispatch:
         assert set(dispatch.available_backends("int8_matmul_t")) == \
             {"pallas-tpu", "pallas-interpret", "ref"}
 
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+    def test_profile_records_tile_source(self, monkeypatch, dtype):
+        """Under REPRO_PROFILE=1 the forward and dx calls record, at trace
+        time, where their tiles came from, tallied over calls."""
+        from repro.kernels import profile
+        monkeypatch.setenv("REPRO_PROFILE", "1")
+        profile.reset()
+        x = _rand(18, (8, 256), dtype)
+        qt = quant.quantize_blockwise(_rand(19, (256, 512), scale=0.1),
+                                      bits=8, symmetric=True)
+
+        def f(x):
+            return jnp.sum(ops.quantized_dense(
+                x, qt, dtype=dtype, backend="pallas-interpret"))
+
+        jax.make_jaxpr(jax.grad(f))(x)
+        jax.make_jaxpr(f)(x)
+        snap = profile.snapshot()
+        profile.reset()
+        assert snap["quantized_dense/fwd"]["meta_calls"] == {
+            "backend=pallas-interpret": 2, "tiles=default": 2}
+        assert snap["quantized_dense/dx"]["meta"]["tiles"] == "default"
+        assert snap["quantized_dense/dx"]["meta_calls"]["tiles=default"] == 1
+
     def test_dense_fallback_toggle(self, monkeypatch):
         """QUANTIZED_DENSE=False restores the materialize+einsum path and
         produces the same numbers (the dequant reference)."""

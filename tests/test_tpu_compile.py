@@ -18,7 +18,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.quant import QTensor
-from repro.kernels import ops
+from repro.kernels import dispatch, ops
 
 D, FF, FF_PAD, VOCAB, RANK, BLOCK = 2048, 5461, 5632, 32000, 128, 256
 BENCH_TESTS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -90,6 +90,38 @@ def test_int8_matmul_t_compiles(one_chip, rows, k, n_pad, n):
     def fwd_bwd(x, w, g):
         out, vjp = jax.vjp(
             lambda x: ops.quantized_dense_t(x, w, backend="pallas-tpu"), x)
+        return out, vjp(g)
+
+    text = _compiled_text(fwd_bwd, x, w, g)
+    assert text.count("tpu_custom_call") >= 2
+
+
+# (K, padded N, virtual N) of every quantized matmul of one training step
+# of the benchmark's InternLM2-1.8B cell (hidden 2048, 8 KV heads of 128,
+# d_ff 8192, vocab 92553), at its 32 x 256 rows per update.
+TRAIN_ROWS = 8192
+TRAIN_MATMULS = [(2048, 2048, 2048), (2048, 1024, 1024), (2048, 8192, 8192),
+                 (8192, 2048, 2048), (2048, 92672, 92553)]
+
+
+@pytest.mark.parametrize("k,n_pad,n", TRAIN_MATMULS,
+                         ids=["attn_q_o", "attn_kv", "ffn_up", "ffn_down",
+                              "head"])
+def test_training_shapes_compile_at_measured_tiles(one_chip, k, n_pad, n):
+    """``int8_matmul`` (forward) and ``int8_matmul_t`` (dx) through
+    ``quantized_dense`` at the training step's shapes, with the measured
+    tiles the committed table gives them: a tile that overflows the
+    scoped VMEM fails here."""
+    for op in ("int8_matmul", "int8_matmul_t"):
+        assert dispatch.tuned_lookup(op, (TRAIN_ROWS, k), "bfloat16",
+                                     backend="pallas-tpu")[1] == "measured"
+    w = _int8_weight(one_chip, k, n_pad, n)
+    x = _sds(one_chip, (TRAIN_ROWS, k), jnp.bfloat16)
+    g = _sds(one_chip, (TRAIN_ROWS, n), jnp.bfloat16)
+
+    def fwd_bwd(x, w, g):
+        out, vjp = jax.vjp(
+            lambda x: ops.quantized_dense(x, w, backend="pallas-tpu"), x)
         return out, vjp(g)
 
     text = _compiled_text(fwd_bwd, x, w, g)
